@@ -20,8 +20,9 @@ def main():
     args = ap.parse_args()
 
     cfg = smoke_config(args.arch)
-    tokens, talp = serve(cfg, requests=args.requests,
-                         prompt_len=args.prompt_len, gen_len=args.gen_len)
+    tokens, talp, *_ = serve(cfg, requests=args.requests,
+                             prompt_len=args.prompt_len,
+                             gen_len=args.gen_len)
     print(f"generated token matrix: {tokens.shape} "
           f"(requests × new tokens)")
     decode = talp.regions["decode"]

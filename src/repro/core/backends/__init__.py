@@ -4,8 +4,10 @@ from .runtime import RuntimeBackend
 from .analytical import (
     AnalyticalBackend,
     HardwareSpec,
+    PEAKS,
     StepModel,
     TPU_V5E,
+    device_peak,
     trace_from_step_model,
 )
 
@@ -19,7 +21,9 @@ __all__ = [
     "RuntimeBackend",
     "AnalyticalBackend",
     "HardwareSpec",
+    "PEAKS",
     "StepModel",
     "TPU_V5E",
+    "device_peak",
     "trace_from_step_model",
 ]

@@ -29,21 +29,41 @@ import numpy as np
 from ..analysis import TraceAnalysis, analyze_trace
 from ..states import DeviceActivity, Trace
 
-__all__ = ["HardwareSpec", "TPU_V5E", "StepModel", "AnalyticalBackend",
-           "trace_from_step_model"]
+__all__ = ["HardwareSpec", "PEAKS", "TPU_V5E", "device_peak", "StepModel",
+           "AnalyticalBackend", "trace_from_step_model"]
 
 
 @dataclass(frozen=True)
 class HardwareSpec:
-    """Per-chip hardware constants (defaults: TPU v5e, task spec)."""
+    """Per-chip peak rates (defaults: TPU v5e)."""
 
     name: str = "tpu_v5e"
     peak_flops: float = 197e12      # bf16 FLOP/s per chip
     hbm_bw: float = 819e9           # bytes/s per chip
-    ici_bw: float = 50e9            # bytes/s per link
+    ici_bw: float = 200e9           # bytes/s per chip (1,600 Gbit/s)
 
 
-TPU_V5E = HardwareSpec()
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+#: 819 GB/s HBM, 1,600 Gbit/s inter-chip interconnect.
+PEAKS = {"TPU v5 lite": HardwareSpec()}
+TPU_V5E = PEAKS["TPU v5 lite"]
+
+
+def device_peak(device) -> Optional[HardwareSpec]:
+    """Peak rates of a ``jax.Device``: ``None`` off the TPU, where no peak
+    applies and Computational Efficiency is not measured. A TPU whose
+    ``device_kind`` is not in :data:`PEAKS` is an error, never a
+    default."""
+    if device.platform != "tpu":
+        return None
+    try:
+        return PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peak for TPU kind {device.device_kind!r}; add "
+            f"it to PEAKS with its source"
+        ) from None
 
 
 @dataclass(frozen=True)

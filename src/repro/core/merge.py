@@ -978,9 +978,8 @@ class AllGatherTransport:
     With multiple initialized JAX processes, every rank contributes its
     JSON payload through ``multihost_utils.process_allgather`` (padded
     uint8 buffers, since collectives move arrays, not strings) and every
-    rank returns the merged job result. On a single process — or when JAX
-    distributed is unavailable — it degenerates to a local merge, so call
-    sites need no gating.
+    rank returns the merged job result. On a single process it degenerates
+    to a local merge, so call sites need no gating.
     """
 
     def __init__(self, max_bytes: int = 1 << 20):
@@ -989,12 +988,9 @@ class AllGatherTransport:
     def gather(self, result: TalpResult, name: Optional[str] = None) -> TalpResult:
         from .report import to_json
 
-        try:
-            import jax
+        import jax
 
-            n_proc = jax.process_count()
-        except Exception:
-            n_proc = 1
+        n_proc = jax.process_count()
         if n_proc <= 1:
             return merge_results([result], name=name)
 

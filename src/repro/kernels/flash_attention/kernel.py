@@ -140,30 +140,26 @@ def flash_attention(
         causal=causal, window=window, softcap=softcap,
         scale=d ** -0.5,
     )
+    # Head-major layout: Mosaic requires the last two block dims to be
+    # (multiple of 8, multiple of 128) or the full array dims, so a block
+    # squeezes the batch and head axes and tiles (sequence, head_dim).
+    qh = jnp.swapaxes(q, 1, 2)               # (B, H, S, D)
+    kh = jnp.swapaxes(k, 1, 2)               # (B, K, T, D)
+    vh = jnp.swapaxes(v, 1, 2)
+    q_spec = pl.BlockSpec(
+        (None, None, bq, d),
+        lambda bb, kk, gg, ii, jj: (bb, kk * g + gg, ii, 0),
+    )
+    kv_spec = pl.BlockSpec(
+        (None, None, bk, d),
+        lambda bb, kk, gg, ii, jj: (bb, kk, jj, 0),
+    )
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[
-            # q: (B,S,H,D) → tile (bq, D) at (batch, q-block, head)
-            pl.BlockSpec(
-                (None, bq, None, d),
-                lambda bb, kk, gg, ii, jj: (bb, ii, kk * g + gg, 0),
-            ),
-            # k/v: (B,T,K,D) → tile (bk, D) at (batch, kv-block, kv-head)
-            pl.BlockSpec(
-                (None, bk, None, d),
-                lambda bb, kk, gg, ii, jj: (bb, jj, kk, 0),
-            ),
-            pl.BlockSpec(
-                (None, bk, None, d),
-                lambda bb, kk, gg, ii, jj: (bb, jj, kk, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (None, bq, None, d),
-            lambda bb, kk, gg, ii, jj: (bb, ii, kk * g + gg, 0),
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, s, h, d), q.dtype),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),   # m (running max)
             pltpu.VMEM((bq, 128), jnp.float32),   # l (running denom)
@@ -171,4 +167,4 @@ def flash_attention(
         ],
         interpret=interpret,
     )
-    return out(q, k, v)
+    return jnp.swapaxes(out(qh, kh, vh), 1, 2)

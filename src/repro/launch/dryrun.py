@@ -156,8 +156,6 @@ def _compile_cell(cfg, shape, mesh):
 def _cost_triplet(compiled):
     """(flops, hbm bytes, collective-bytes-by-kind) of a compiled module."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0]
     stats = collective_bytes_from_hlo(compiled.as_text())
     return (
         float(cost.get("flops", 0.0)),

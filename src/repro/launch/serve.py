@@ -15,9 +15,11 @@ Usage (CPU-sized):
 from __future__ import annotations
 
 import argparse
+import functools
 import sys
 import time
 from contextlib import nullcontext
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -27,11 +29,25 @@ from ..configs import ShapeConfig, get_config, list_configs, smoke_config
 from ..core.backends import RuntimeBackend
 from ..core.merge import FileSpoolTransport, emit_job_report
 from ..core.report import render_tables, to_json
-from ..core.talp import TalpMonitor
+from ..core.talp import TalpMonitor, TalpResult
 from ..models import lm
-from .steps import make_prefill_step, make_serve_step, model_flops
+from .compile_cache import enable_compile_cache
+from .steps import (
+    init_serve_params, make_prefill_step, make_serve_step, step_flop_model,
+)
 
-__all__ = ["serve", "main"]
+__all__ = ["ServeResult", "serve", "main"]
+
+
+class ServeResult(NamedTuple):
+    """What one :func:`serve` call produced."""
+
+    tokens: np.ndarray      # (requests, gen_len) generated token ids
+    talp: TalpResult
+    params: Any             # the bf16 weights the batch was served with
+    prompts: jax.Array      # (requests, prompt_len[, d_model]) inputs
+    logits: jax.Array       # (requests, padded_vocab) last decode step
+    token_s: np.ndarray     # (gen_len,) wall seconds of each decode step
 
 
 def serve(
@@ -69,8 +85,9 @@ def serve(
     ``talp_anomaly_log`` mirror the training driver at decode-token
     resolution: each decode iteration runs in a nested ``decode_step``
     region whose close feeds the per-step ring and the anomaly
-    watchdog. The decode-shape FLOP estimate feeds the measured
-    Computational Efficiency annotation. ``talp_fault_plan`` injects
+    watchdog. On a TPU the decode step's model FLOPs over the device's
+    published peak feed the measured Computational Efficiency
+    annotation. ``talp_fault_plan`` injects
     deterministic collection faults for this rank (debug) — see
     :class:`repro.core.collect.FaultPlan`."""
     from ..core.collect import FaultPlan
@@ -87,16 +104,11 @@ def serve(
                   f"{fault_plan.describe(rank)}")
     backend = RuntimeBackend()
     want_steps = bool(talp_step_series or talp_watchdog or talp_anomaly_log)
-    flop_model = None
-    if want_steps:
-        from ..core.backends.analytical import StepModel
-
-        shape = ShapeConfig(name="serve", seq_len=prompt_len + gen_len,
-                            global_batch=requests, kind="decode")
-        flop_model = StepModel(
-            flops=0.0, hbm_bytes=0.0, collective_bytes=0.0,
-            model_flops=model_flops(cfg, shape) / max(world_size, 1),
-        )
+    flop_model = step_flop_model(
+        cfg, ShapeConfig(name="serve", seq_len=prompt_len + gen_len,
+                         global_batch=requests, kind="decode"),
+        world_size,
+    ) if want_steps else None
     mon = TalpMonitor("serve", rank=rank, clock=clock, backend=backend,
                       overhead_report=True, flop_model=flop_model)
     step_recorder = step_watchdog = None
@@ -146,16 +158,15 @@ def serve(
     key = jax.random.PRNGKey(seed)
 
     with mon.region("init"):
-        params = lm.init_params(cfg, key)
-        params = jax.tree.map(
-            lambda x: x.astype(jnp.bfloat16)
-            if jnp.issubdtype(x.dtype, jnp.floating) else x,
-            params,
-        )
+        params = jax.jit(functools.partial(init_serve_params, cfg))(key)
         params = jax.block_until_ready(params)
 
     prefill_fn = jax.jit(make_prefill_step(cfg))
     decode_fn = jax.jit(make_serve_step(cfg), donate_argnums=3)
+    # Flushes the hot ring into the prefix cache before the ring wraps
+    # (``attn_decode`` writes it at ``pos % decode_hot_len``).
+    consolidate_fn = jax.jit(functools.partial(lm.consolidate_caches, cfg),
+                             donate_argnums=0)
 
     if cfg.frontend == "token":
         prompts = jax.random.randint(
@@ -167,6 +178,7 @@ def serve(
         )
 
     tokens_out = []
+    token_s = np.zeros(gen_len)
     with mon.region("prefill"):
         h = backend.launch(prefill_fn, params, prompts, name="prefill")
         with mon.offload():
@@ -177,6 +189,7 @@ def serve(
     tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
     with mon.region("decode"):
         for t in range(gen_len):
+            t0 = time.perf_counter()
             with (mon.region("decode_step") if step_recorder is not None
                   else nullcontext()):
                 tokens_out.append(np.asarray(tok))
@@ -190,6 +203,9 @@ def serve(
                     logits, caches, pos = backend.wait(h)
                 tok = jnp.argmax(
                     logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+                if (t + 1) % cfg.decode_hot_len == 0 and t + 1 < gen_len:
+                    caches = consolidate_fn(caches)
+            token_s[t] = time.perf_counter() - t0
             if talp_sample_every and (t + 1) % talp_sample_every == 0:
                 sample_snapshot(f"token {t}")
 
@@ -232,7 +248,8 @@ def serve(
                         fault_plan=fault_plan)
     if step_watchdog is not None:
         step_watchdog.close()
-    return np.stack(tokens_out, axis=1), result
+    return ServeResult(np.stack(tokens_out, axis=1), result, params,
+                       prompts, logits, token_s)
 
 
 def main():
@@ -276,20 +293,21 @@ def main():
     ap.add_argument("--rank", type=int, default=0)
     ap.add_argument("--world-size", type=int, default=1)
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     t0 = time.time()
-    tokens, _ = serve(cfg, args.requests, args.prompt_len, args.gen_len,
-                      talp_json=args.talp_json, rank=args.rank,
-                      world_size=args.world_size, talp_spool=args.talp_spool,
-                      talp_sample_every=args.talp_sample_every,
-                      talp_spool_format=args.talp_spool_format,
-                      talp_trace_out=args.talp_trace_out,
-                      talp_metrics_jsonl=args.talp_metrics_jsonl,
-                      talp_prometheus_port=args.talp_prometheus_port,
-                      talp_step_series=args.talp_step_series,
-                      talp_watchdog=args.talp_watchdog,
-                      talp_anomaly_log=args.talp_anomaly_log,
-                      talp_fault_plan=args.talp_fault_plan)
+    tokens = serve(cfg, args.requests, args.prompt_len, args.gen_len,
+                   talp_json=args.talp_json, rank=args.rank,
+                   world_size=args.world_size, talp_spool=args.talp_spool,
+                   talp_sample_every=args.talp_sample_every,
+                   talp_spool_format=args.talp_spool_format,
+                   talp_trace_out=args.talp_trace_out,
+                   talp_metrics_jsonl=args.talp_metrics_jsonl,
+                   talp_prometheus_port=args.talp_prometheus_port,
+                   talp_step_series=args.talp_step_series,
+                   talp_watchdog=args.talp_watchdog,
+                   talp_anomaly_log=args.talp_anomaly_log,
+                   talp_fault_plan=args.talp_fault_plan).tokens
     dt = time.time() - t0
     n = tokens.size
     print(f"generated {n} tokens in {dt:.2f}s ({n/dt:.1f} tok/s)")

@@ -11,12 +11,13 @@ lower ``serve_step`` (one new token against a seq_len cache).
 from __future__ import annotations
 
 import functools
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig, ShapeConfig
+from ..core.backends.analytical import StepModel, device_peak
 from ..models import lm
 from ..optim.adamw import AdamWConfig, adamw_update, init_opt_state
 
@@ -27,7 +28,9 @@ __all__ = [
     "make_prefill_step",
     "make_serve_step",
     "input_specs",
+    "init_serve_params",
     "serve_params_shapes",
+    "step_flop_model",
     "model_flops",
 ]
 
@@ -85,17 +88,20 @@ def make_train_step(cfg: ModelConfig, opt_cfg: AdamWConfig = AdamWConfig()):
 # ---------------------------------------------------------------------------
 # serve
 # ---------------------------------------------------------------------------
-def serve_params_shapes(cfg: ModelConfig):
-    """Serving weights are bf16 (fp32 masters live in the train state)."""
-    shapes = jax.eval_shape(
-        functools.partial(lm.init_params, cfg), jax.random.PRNGKey(0)
-    )
+def init_serve_params(cfg: ModelConfig, key) -> Dict[str, Any]:
+    """Serving weights are bf16 (fp32 masters live in the train state).
+    Under one ``jit`` the fp32 initial values are temporaries of the cast,
+    so only the bf16 weights are ever held as a whole."""
     return jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(
-            s.shape,
-            jnp.bfloat16 if jnp.issubdtype(s.dtype, jnp.floating) else s.dtype,
-        ),
-        shapes,
+        lambda x: x.astype(jnp.bfloat16)
+        if jnp.issubdtype(x.dtype, jnp.floating) else x,
+        lm.init_params(cfg, key),
+    )
+
+
+def serve_params_shapes(cfg: ModelConfig):
+    return jax.eval_shape(
+        functools.partial(init_serve_params, cfg), jax.random.PRNGKey(0)
     )
 
 
@@ -161,3 +167,17 @@ def model_flops(cfg: ModelConfig, shape: ShapeConfig) -> float:
     if shape.kind == "prefill":
         return 2.0 * n * shape.global_batch * shape.seq_len
     return 2.0 * n * shape.global_batch  # decode: 1 new token
+
+
+def step_flop_model(cfg: ModelConfig, shape: ShapeConfig,
+                    world_size: int = 1) -> Optional[StepModel]:
+    """Per-device model FLOPs of one step with the peaks of the device
+    JAX runs on, for TALP's Computational Efficiency. ``None`` off the
+    TPU: there is no peak, so that metric is not measured."""
+    hw = device_peak(jax.devices()[0])
+    if hw is None:
+        return None
+    return StepModel(
+        flops=0.0, hbm_bytes=0.0, collective_bytes=0.0,
+        model_flops=model_flops(cfg, shape) / max(world_size, 1), hw=hw,
+    )

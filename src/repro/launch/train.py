@@ -39,8 +39,9 @@ from ..core.talp import TalpMonitor
 from ..data.pipeline import DataConfig, SyntheticTokenPipeline
 from ..optim.adamw import AdamWConfig
 from ..runtime.fault_tolerance import StragglerDetector
+from .compile_cache import enable_compile_cache
 from .steps import (
-    init_train_state, make_train_step, model_flops, train_state_shapes,
+    init_train_state, make_train_step, step_flop_model, train_state_shapes,
 )
 
 __all__ = ["train", "main"]
@@ -97,8 +98,9 @@ def train(
     ring is spooled and rank-aligned into a job-level per-step table).
     ``talp_watchdog`` runs the online anomaly watchdog over those rows;
     ``talp_anomaly_log`` streams its events as JSONL (either implies the
-    step series). The step model's FLOP estimate feeds the measured
-    Computational Efficiency annotation.
+    step series). On a TPU the step's model FLOPs over the device's
+    published peak feed the measured Computational Efficiency annotation;
+    elsewhere that annotation is absent.
 
     Debugging the fault-tolerant collection path: ``talp_fault_plan`` (a
     :class:`~repro.core.collect.FaultPlan` spec — inline JSON or a file
@@ -120,16 +122,11 @@ def train(
     opt_cfg = opt_cfg or AdamWConfig(warmup_steps=10, total_steps=steps)
     backend = RuntimeBackend()
     want_steps = bool(talp_step_series or talp_watchdog or talp_anomaly_log)
-    flop_model = None
-    if want_steps:
-        from ..core.backends.analytical import StepModel
-
-        shape = ShapeConfig(name="train", seq_len=seq_len,
-                            global_batch=global_batch, kind="train")
-        flop_model = StepModel(
-            flops=0.0, hbm_bytes=0.0, collective_bytes=0.0,
-            model_flops=model_flops(cfg, shape) / max(world_size, 1),
-        )
+    flop_model = step_flop_model(
+        cfg, ShapeConfig(name="train", seq_len=seq_len,
+                         global_batch=global_batch, kind="train"),
+        world_size,
+    ) if want_steps else None
     mon = TalpMonitor("train", rank=rank, clock=clock, backend=backend,
                       overhead_report=True, flop_model=flop_model)
     step_recorder = step_watchdog = None
@@ -343,6 +340,7 @@ def main():
     ap.add_argument("--history-json", default=None)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     _, history, _ = train(
         cfg,

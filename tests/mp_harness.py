@@ -39,13 +39,17 @@ SMOKE_ARCH = "llama3.2-3b"
 SMOKE_ARGS = ("--steps", "3", "--batch", "6", "--seq", "16")
 
 
-def fleet_env() -> Dict[str, str]:
-    """Subprocess environment: repo sources importable, CPU-only JAX."""
+def fleet_env(cache_dir: str) -> Dict[str, str]:
+    """Subprocess environment: repo sources importable, CPU-only JAX (a
+    chip belongs to one process, so rank fleets are CPU fleets), and the
+    drivers' compilation cache in ``cache_dir``, a directory the caller
+    owns, so that a run leaves nothing in the checkout."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC_DIR + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
     )
     env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
     return env
 
 
@@ -99,9 +103,11 @@ def launch_fleet(
     the tiny smoke sizes; ``extra_args`` append to every rank,
     ``per_rank_args[i]`` to rank *i* only (how fault-plan flags reach a
     single rank). Processes are launched together and awaited together —
-    the ranks genuinely race on the shared spool directory.
+    the ranks genuinely race on the shared spool directory. The
+    compilation cache goes next to the spool, in ``jax_cache``.
     """
-    env = fleet_env()
+    env = fleet_env(os.path.join(
+        os.path.dirname(os.path.abspath(spool_dir)), "jax_cache"))
     if env_extra:
         env.update(env_extra)
     procs = []
@@ -182,7 +188,7 @@ def launch_allgather_fleet(
     ``AllGatherTransport`` collective; each rank writes the job report it
     obtained to ``<out_dir>/job_rank<i>.json`` (every rank must obtain
     the identical merged result)."""
-    env = fleet_env()
+    env = fleet_env(os.path.join(out_dir, "jax_cache"))
     coordinator = f"127.0.0.1:{free_port()}"
     procs = []
     for rank in range(n_ranks):

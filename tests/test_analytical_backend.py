@@ -1,16 +1,20 @@
 """Analytical backend: roofline-derived device states → paper metrics,
 plus the beyond-paper Device Computational Efficiency branch."""
 
+from types import SimpleNamespace
+
 import pytest
 
 from repro.core.analysis import analyze_trace
-from repro.core.backends import HardwareSpec, StepModel, TPU_V5E, trace_from_step_model
+from repro.core.backends import (
+    HardwareSpec, StepModel, TPU_V5E, device_peak, trace_from_step_model,
+)
 from repro.core.backends.analytical import AnalyticalBackend
 from repro.core.report import node_scan_table
 
 
 def test_step_model_terms():
-    m = StepModel(flops=197e12, hbm_bytes=819e9, collective_bytes=50e9)
+    m = StepModel(flops=197e12, hbm_bytes=819e9, collective_bytes=200e9)
     assert m.compute_s == pytest.approx(1.0)
     assert m.hbm_s == pytest.approx(1.0)
     assert m.collective_s == pytest.approx(1.0)
@@ -27,7 +31,7 @@ def test_compute_bound_vs_memory_bound():
 
 
 def test_balanced_trace_metrics():
-    m = StepModel(flops=197e12, hbm_bytes=0.5 * 819e9, collective_bytes=0.25 * 50e9)
+    m = StepModel(flops=197e12, hbm_bytes=0.5 * 819e9, collective_bytes=0.25 * 200e9)
     tr = trace_from_step_model([m, m], steps=3)
     a = analyze_trace(tr)
     a.validate()
@@ -68,8 +72,8 @@ def test_computational_efficiency_extension():
 
 
 def test_collective_overlap_knob():
-    m0 = StepModel(flops=197e12, hbm_bytes=0, collective_bytes=50e9)
-    m1 = StepModel(flops=197e12, hbm_bytes=0, collective_bytes=50e9,
+    m0 = StepModel(flops=197e12, hbm_bytes=0, collective_bytes=200e9)
+    m1 = StepModel(flops=197e12, hbm_bytes=0, collective_bytes=200e9,
                    collective_overlap=0.75)
     assert m0.memory_s == pytest.approx(1.0)
     assert m1.memory_s == pytest.approx(0.25)
@@ -90,4 +94,16 @@ def test_node_scan_table_renders():
 def test_default_hw_is_v5e():
     assert TPU_V5E.peak_flops == pytest.approx(197e12)
     assert TPU_V5E.hbm_bw == pytest.approx(819e9)
-    assert TPU_V5E.ici_bw == pytest.approx(50e9)
+    assert TPU_V5E.ici_bw == pytest.approx(1600e9 / 8)   # 1,600 Gbit/s
+
+
+def test_device_peak_by_kind():
+    v5e = SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    assert device_peak(v5e) is TPU_V5E
+    cpu = SimpleNamespace(platform="cpu", device_kind="cpu")
+    assert device_peak(cpu) is None
+
+
+def test_device_peak_unknown_tpu_kind_raises():
+    with pytest.raises(ValueError, match="TPU v99"):
+        device_peak(SimpleNamespace(platform="tpu", device_kind="TPU v99"))

@@ -157,10 +157,7 @@ def test_roofline_calibration_semantics():
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         def costs(compiled):
-            # jax returns either a dict or a one-element list of dicts
-            # depending on version
-            c = compiled.cost_analysis()
-            return c[0] if isinstance(c, (list, tuple)) else c
+            return compiled.cost_analysis()
 
         # large enough that XLA partitions instead of replicating
         x = jax.ShapeDtypeStruct((1024, 1024), jnp.float32)

@@ -87,7 +87,8 @@ def test_fault_injected_fleet_partial_merge(tmp_path):
         [sys.executable, "-m", "repro.core.merge", str(spool),
          "--name", "job", "--allow-missing-ranks", "--expected-ranks", "3",
          "--json-out", str(out)],
-        capture_output=True, text=True, env=fleet_env(),
+        capture_output=True, text=True,
+        env=fleet_env(str(tmp_path / "jax_cache")),
     )
     assert proc.returncode == 0, proc.stderr
     job = json.loads(out.read_text())

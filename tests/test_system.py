@@ -41,8 +41,8 @@ def test_train_loss_decreases_with_talp(tmp_path):
 @pytest.mark.slow
 def test_serve_generates_and_reports(tmp_path):
     cfg = smoke_config("h2o-danube-3-4b")   # SWA ring-cache path
-    tokens, talp = serve(cfg, requests=2, prompt_len=16, gen_len=6,
-                         verbose=False)
+    tokens, talp, *_ = serve(cfg, requests=2, prompt_len=16, gen_len=6,
+                             verbose=False)
     assert tokens.shape == (2, 6)
     assert np.all(tokens >= 0) and np.all(tokens < cfg.vocab_size)
     dec = talp.regions["decode"]
@@ -57,8 +57,8 @@ def test_embed_frontend_end_to_end():
     _, history, _ = train(cfg, steps=8, global_batch=2, seq_len=32,
                           verbose=False)
     assert np.isfinite(history[-1]["loss"])
-    tokens, _ = serve(cfg, requests=2, prompt_len=8, gen_len=3,
-                      verbose=False)
+    tokens = serve(cfg, requests=2, prompt_len=8, gen_len=3,
+                   verbose=False).tokens
     assert tokens.shape == (2, 3)
 
 
@@ -92,3 +92,26 @@ def test_consolidate_caches_roundtrip():
         np.asarray(l1, np.float32), np.asarray(l2, np.float32),
         rtol=2e-2, atol=2e-2,
     )
+
+
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "zamba2-2.7b"])
+def test_serve_past_hot_ring_matches_prefill(arch):
+    """Decoding more tokens than the hot ring holds keeps every generated
+    token in context: the last decode step's logits equal one prefill over
+    the prompt plus the generated tokens, with the same weights.
+
+    Tolerance: bf16 weights and activations, and decode and prefill reduce
+    in different orders (recurrent vs chunked SSD, split vs chunked
+    softmax); at these sizes that costs < 0.05, while a ring that wraps
+    without a flush is off by > 0.2."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg = smoke_config(arch)
+    gen_len = cfg.decode_hot_len + 8
+    out = serve(cfg, requests=2, prompt_len=16, gen_len=gen_len,
+                verbose=False)
+    full = jnp.concatenate([out.prompts, jnp.asarray(out.tokens)], axis=1)
+    ref, _, _ = jax.jit(lambda p, x: lm.prefill(cfg, p, x))(out.params, full)
+    np.testing.assert_allclose(np.asarray(out.logits), np.asarray(ref),
+                               rtol=0, atol=0.1)
