@@ -28,7 +28,6 @@ from typing import Any, Callable, List, Optional, Tuple
 import numpy as np
 
 from ..states import DeviceActivity, DeviceRecord
-from ..telemetry import overhead as _ovh
 from .base import register_backend
 
 __all__ = ["RuntimeBackend", "AsyncHandle"]
@@ -107,12 +106,10 @@ class RuntimeBackend:
 
     def flush_arrays(self):
         """Drain buffered activity as per-device column batches."""
-        with _ovh.section("flush"):
-            out = [
-                (dev, *self._columns[dev].drain())
-                for dev in sorted(self._columns)
-            ]
-            return out
+        return [
+            (dev, *self._columns[dev].drain())
+            for dev in sorted(self._columns)
+        ]
 
     def flush(self):
         """Legacy object path: materialize ``DeviceRecord`` per event."""

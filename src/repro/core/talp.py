@@ -14,6 +14,9 @@ Mirrors TALP's design:
   * **Online + post-mortem**: ``sample()`` returns live metrics;
     ``finalize()`` produces the full per-region report (text/JSON via
     :mod:`repro.core.report`).
+  * **On a profiler's timeline**: region windows, host-state scopes and
+    the monitor's own work are also spans (:mod:`.telemetry.spans`), so
+    a ``jax.profiler`` trace shows them beside the device's operations.
 
 Transparency: ``monitor.instrument(fn)`` wraps a jitted callable so the
 application code needs no changes (≙ LD_PRELOAD).
@@ -33,9 +36,13 @@ from .device_metrics import DeviceMetrics, device_metrics
 from .host_metrics import HostMetrics, host_metrics
 from .states import DeviceActivity, DeviceTimeline, HostState
 from .telemetry import overhead as _ovh
+from .telemetry import spans as _spans
 from .tree import MetricNode, device_tree, host_tree
 
 __all__ = ["TalpMonitor", "RegionResult", "TalpResult", "StepCloseEvent"]
+
+#: Profiler span of each non-useful host state scope.
+_STATE_SPANS = {HostState.OFFLOAD: "talp.offload", HostState.MPI: "talp.mpi"}
 
 
 @dataclass(frozen=True)
@@ -83,6 +90,8 @@ class _RegionAcc:
     closed_total: float = 0.0
     open_offload: float = 0.0
     open_mpi: float = 0.0
+    #: The open window's ``talp.region.<name>`` span token.
+    span: object = field(default=None, repr=False, compare=False)
     _flat: Optional[np.ndarray] = field(
         default=None, init=False, repr=False, compare=False
     )
@@ -221,6 +230,7 @@ class TalpMonitor:
         acc = self._acc.setdefault(name, _RegionAcc())
         if acc.open_since is not None:
             raise RuntimeError(f"region {name!r} already open")
+        acc.span = _spans.begin(f"talp.region.{name}", step=len(acc.windows))
         acc.open_since = self.clock()
         acc.open_offload = acc.offload
         acc.open_mpi = acc.mpi
@@ -259,6 +269,8 @@ class TalpMonitor:
         acc.windows.append((t_open, now))
         acc.closed_total += now - t_open
         acc.open_since = None
+        _spans.end(acc.span)
+        acc.span = None
         self._region_stack.pop()
         if self._close_callbacks:
             d_off = acc.offload - acc.open_offload
@@ -291,11 +303,13 @@ class TalpMonitor:
         if self._state is not None:
             raise RuntimeError(f"nested host state {state} inside {self._state}")
         self._state = state
+        token = _spans.begin(_STATE_SPANS[state])
         t0 = self.clock()
         try:
             yield
         finally:
             dt = self.clock() - t0
+            _spans.end(token)
             self._state = None
             self._charge(state, dt)
 
@@ -335,7 +349,7 @@ class TalpMonitor:
     ) -> int:
         """Batch entry point: deliver one whole activity buffer for a
         device as columns (see :meth:`DeviceTimeline.ingest_arrays`)."""
-        t0 = self.overhead.begin()
+        t0 = self.overhead.begin("ingest")
         try:
             return self.device(dev).ingest_arrays(kinds, starts, ends, streams)
         finally:
@@ -345,7 +359,7 @@ class TalpMonitor:
         be = self.backend
         if be is None:
             return
-        t0 = self.overhead.begin()
+        t0 = self.overhead.begin("ingest")
         try:
             if hasattr(be, "flush_arrays"):
                 # Columnar path: whole activity buffers, zero per-event objects.
@@ -427,7 +441,7 @@ class TalpMonitor:
         flattened pair is rebuilt from those, and an unchanged timeline
         is a pure cache hit — no re-flattening of the whole history.
         """
-        t0 = self.overhead.begin()
+        t0 = self.overhead.begin("flatten")
         try:
             flats: Dict[int, Tuple[np.ndarray, np.ndarray]] = {}
             for dev, tl in sorted(self.devices.items()):
@@ -549,7 +563,7 @@ class TalpMonitor:
 
     def sample(self, region: Optional[str] = None) -> RegionResult:
         """Online metrics for an open (or closed) region — TALP's runtime mode."""
-        t0 = self.overhead.begin()
+        t0 = self.overhead.begin("sample")
         try:
             self._flush_backend()
             return self._region_result(
@@ -568,7 +582,7 @@ class TalpMonitor:
         the run (e.g. on a ``--talp-sample-every`` cadence) and merged
         across ranks into a job-level mid-run report.
         """
-        t0 = self.overhead.begin()
+        t0 = self.overhead.begin("sample")
         try:
             self._flush_backend()
             now = self.clock()
@@ -586,7 +600,7 @@ class TalpMonitor:
         now = self.clock()
         while self._region_stack:
             self.close_region(self._region_stack[-1])
-        t0 = self.overhead.begin()
+        t0 = self.overhead.begin("sample")
         try:
             self._flush_backend()
             if self.backend is not None and hasattr(self.backend, "stop"):

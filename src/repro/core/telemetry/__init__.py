@@ -1,7 +1,7 @@
-"""Observability subsystem: trace export, runtime metric stream, and
-TALP self-overhead accounting.
+"""Observability subsystem: trace export, runtime metric stream, TALP
+self-overhead accounting, and profiler spans.
 
-Three pillars (see the module docstrings):
+Four pillars (see the module docstrings):
 
   * :mod:`.traceexport` — Chrome trace-event JSON (Perfetto /
     ``chrome://tracing``) rendered vectorized from the columnar buffers.
@@ -9,6 +9,8 @@ Three pillars (see the module docstrings):
     ``sample_result()`` snapshots published as JSONL + Prometheus text.
   * :mod:`.overhead` — monotonic-clock accounting of the monitor's own
     hot paths, surfaced as the optional ``talp_overhead`` report branch.
+  * :mod:`.spans` — TALP's regions, host states and overhead sections,
+    and the drivers' loop phases, as named spans on a profiler's timeline.
 
 Plus the step-resolution pair built on all three:
 
@@ -17,11 +19,11 @@ Plus the step-resolution pair built on all three:
   * :mod:`.watchdog` — online :class:`EfficiencyWatchdog` with rolling
     EWMA/CUSUM baselines, hysteresis, and hierarchy-aware attribution.
 
-Only :mod:`.overhead` is imported eagerly: it is dependency-free and the
-core measurement modules (``states``/``talp``/``merge``) time their hot
-paths against it, so it must never pull the exporters (which import
-those same core modules) back in. Everything else loads lazily on first
-attribute access.
+Only :mod:`.overhead` and :mod:`.spans` are imported eagerly: they are
+dependency-free and the core measurement modules (``states``/``talp``/
+``merge``) time and mark their hot paths with them, so they must never
+pull the exporters (which import those same core modules) back in.
+Everything else loads lazily on first attribute access.
 """
 
 from __future__ import annotations
@@ -29,7 +31,7 @@ from __future__ import annotations
 import importlib
 
 from .overhead import OverheadAccumulator, current, install, section  # noqa: F401
-from . import overhead  # noqa: F401
+from . import overhead, spans  # noqa: F401
 
 __all__ = [
     "OverheadAccumulator",
@@ -37,6 +39,7 @@ __all__ = [
     "install",
     "section",
     "overhead",
+    "spans",
     "traceexport",
     "exporter",
     "stepseries",

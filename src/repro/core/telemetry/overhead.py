@@ -7,7 +7,6 @@ is unknown cannot be left on in production. This module instruments the
 monitor's hot paths with monotonic-clock accumulators:
 
   * ``ingest``  — backend flush + columnar record ingestion,
-  * ``flush``   — a backend draining its own activity buffers,
   * ``compact`` — pending-row folds into the flattened interval arrays,
   * ``flatten`` — per-device flattened-pair construction at sample time,
   * ``sample``  — online snapshot construction (includes nested work),
@@ -18,7 +17,9 @@ monitor's hot paths with monotonic-clock accumulators:
 Sections may nest (a ``sample`` triggers ``flatten`` which may trigger
 ``compact``); per-section totals are *inclusive* while
 :attr:`OverheadAccumulator.total` counts only outermost sections, so the
-wall-clock fraction never double-counts nested work.
+wall-clock fraction never double-counts nested work. Each section is
+also a ``talp.capture.<section>`` span (:mod:`.spans`), so the monitor's
+own work shows on a profiler's timeline beside the device's.
 
 One accumulator is installed process-globally (every
 :class:`~repro.core.talp.TalpMonitor` installs its own at construction;
@@ -33,20 +34,16 @@ from __future__ import annotations
 
 import time
 from contextlib import contextmanager
-from typing import Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
+
+from . import spans as _spans
 
 __all__ = [
-    "SECTIONS",
     "OverheadAccumulator",
     "install",
     "current",
     "section",
 ]
-
-#: Known hot-path section names (free-form names are accepted too).
-SECTIONS = (
-    "ingest", "flush", "compact", "flatten", "sample", "step", "spool", "export",
-)
 
 
 class OverheadAccumulator:
@@ -60,7 +57,8 @@ class OverheadAccumulator:
     driven by synthetic test clocks still measure their real cost.
     """
 
-    __slots__ = ("totals", "counts", "clock", "_depth", "_outer_total")
+    __slots__ = ("totals", "counts", "clock", "_depth", "_outer_total",
+                 "_spans")
 
     def __init__(self, clock: Callable[[], float] = time.perf_counter):
         self.totals: Dict[str, float] = {}
@@ -68,14 +66,17 @@ class OverheadAccumulator:
         self.clock = clock
         self._depth = 0
         self._outer_total = 0.0
+        self._spans: List[Any] = []   # open sections' span tokens, nested
 
     # -- explicit begin/end (hot-path inline form) -----------------------
-    def begin(self) -> float:
+    def begin(self, name: str) -> float:
         self._depth += 1
+        self._spans.append(_spans.begin("talp.capture." + name))
         return self.clock()
 
     def end(self, name: str, t0: float) -> float:
         dt = self.clock() - t0
+        _spans.end(self._spans.pop())
         self._depth -= 1
         self.totals[name] = self.totals.get(name, 0.0) + dt
         self.counts[name] = self.counts.get(name, 0) + 1
@@ -85,7 +86,7 @@ class OverheadAccumulator:
 
     @contextmanager
     def section(self, name: str):
-        t0 = self.begin()
+        t0 = self.begin(name)
         try:
             yield self
         finally:
@@ -118,6 +119,7 @@ class OverheadAccumulator:
         self.counts.clear()
         self._depth = 0
         self._outer_total = 0.0
+        self._spans.clear()
 
 
 # ---------------------------------------------------------------------------
@@ -147,7 +149,7 @@ def section(name: str):
     if acc is None:
         yield None
         return
-    t0 = acc.begin()
+    t0 = acc.begin(name)
     try:
         yield acc
     finally:

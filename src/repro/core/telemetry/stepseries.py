@@ -294,7 +294,7 @@ class StepSeriesRecorder:
     def _on_close(self, mon, ev) -> None:
         if self.regions is not None and ev.region not in self.regions:
             return
-        t0 = mon.overhead.begin()
+        t0 = mon.overhead.begin("step")
         try:
             self._record(mon, ev)
         finally:

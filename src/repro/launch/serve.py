@@ -5,7 +5,10 @@ caches, then decodes tokens autoregressively. Host/device states are
 TALP-monitored exactly as in training — the serving profile typically
 shows high Offload (host blocked on decode steps) and the per-step
 Orchestration gap, which is the paper's framing for "the host cannot
-feed the device."
+feed the device." Each decode step's phases (``serve.fetch``,
+``serve.feed``, ``serve.dispatch``, ``serve.sample``, ``serve.flush``)
+and TALP's own scopes are profiler spans, so a ``jax.profiler`` trace
+puts every idle gap of the device down to one of them.
 
 Usage (CPU-sized):
   PYTHONPATH=src python -m repro.launch.serve --arch gemma2-2b --smoke \
@@ -30,6 +33,7 @@ from ..core.backends import RuntimeBackend
 from ..core.merge import FileSpoolTransport, emit_job_report
 from ..core.report import render_tables, to_json
 from ..core.talp import TalpMonitor, TalpResult
+from ..core.telemetry import spans
 from ..models import lm
 from .compile_cache import enable_compile_cache
 from .steps import (
@@ -92,6 +96,7 @@ def serve(
     :class:`repro.core.collect.FaultPlan`."""
     from ..core.collect import FaultPlan
 
+    spans.install(jax.profiler.TraceAnnotation)
     fault_plan = (FaultPlan.from_spec(talp_fault_plan)
                   if talp_fault_plan is not None else None)
     clock = time.perf_counter
@@ -192,19 +197,25 @@ def serve(
             t0 = time.perf_counter()
             with (mon.region("decode_step") if step_recorder is not None
                   else nullcontext()):
-                tokens_out.append(np.asarray(tok))
-                if cfg.frontend == "token":
-                    inp = tok[:, None]
-                else:  # embed-frontend stub: feed a frame embedding
-                    inp = jnp.zeros((requests, 1, cfg.d_model), jnp.bfloat16)
-                h = backend.launch(decode_fn, params, inp, pos, caches,
-                                   name=f"decode_{t}")
+                with spans.span("serve.fetch"):
+                    tokens_out.append(np.asarray(tok))
+                with spans.span("serve.feed"):
+                    if cfg.frontend == "token":
+                        inp = tok[:, None]
+                    else:  # embed-frontend stub: feed a frame embedding
+                        inp = jnp.zeros((requests, 1, cfg.d_model),
+                                        jnp.bfloat16)
+                with spans.span("serve.dispatch"):
+                    h = backend.launch(decode_fn, params, inp, pos, caches,
+                                       name=f"decode_{t}")
                 with mon.offload():
                     logits, caches, pos = backend.wait(h)
-                tok = jnp.argmax(
-                    logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+                with spans.span("serve.sample"):
+                    tok = jnp.argmax(
+                        logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
                 if (t + 1) % cfg.decode_hot_len == 0 and t + 1 < gen_len:
-                    caches = consolidate_fn(caches)
+                    with spans.span("serve.flush"):
+                        caches = consolidate_fn(caches)
             token_s[t] = time.perf_counter() - t0
             if talp_sample_every and (t + 1) % talp_sample_every == 0:
                 sample_snapshot(f"token {t}")

@@ -8,7 +8,9 @@ Every step runs under TALP regions/states:
   * *MPI*          — cross-process control-plane waits (checkpoint
                      barrier in multi-process runs; ~0 single-process),
 and the paper's text/JSON report is emitted at exit and every
-``--talp-interval`` steps (TALP's online mode). Checkpoint/restart and
+``--talp-interval`` steps (TALP's online mode). The batch and the
+dispatch (``train.batch``, ``train.dispatch``) and TALP's own scopes are
+profiler spans, on the device trace's clock. Checkpoint/restart and
 straggler detection are integrated (fault tolerance), and the data
 pipeline prefetches in the background.
 
@@ -36,6 +38,7 @@ from ..core.backends import RuntimeBackend
 from ..core.merge import FileSpoolTransport, emit_job_report
 from ..core.report import render_tables, to_json
 from ..core.talp import TalpMonitor
+from ..core.telemetry import spans
 from ..data.pipeline import DataConfig, SyntheticTokenPipeline
 from ..optim.adamw import AdamWConfig
 from ..runtime.fault_tolerance import StragglerDetector
@@ -109,6 +112,7 @@ def train(
     """
     from ..core.collect import FaultPlan
 
+    spans.install(jax.profiler.TraceAnnotation)
     fault_plan = (FaultPlan.from_spec(talp_fault_plan)
                   if talp_fault_plan is not None else None)
     clock = time.perf_counter
@@ -195,11 +199,13 @@ def train(
             with (mon.region("step") if step_recorder is not None
                   else nullcontext()):
                 # host Useful: data synthesis (prefetch keeps this short)
-                batch = data.batch_at(step)
-                batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                with spans.span("train.batch"):
+                    batch = data.batch_at(step)
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
                 # Offload: dispatch + block (async launch → kernel record)
-                handle = backend.launch(step_fn, state, batch,
-                                        name="train_step")
+                with spans.span("train.dispatch"):
+                    handle = backend.launch(step_fn, state, batch,
+                                            name="train_step")
                 with mon.offload():
                     state, metrics = backend.wait(handle)
                 if manager is not None and (step + 1) % ckpt_every == 0:

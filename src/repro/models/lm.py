@@ -114,44 +114,52 @@ def param_count(params) -> int:
 # ---------------------------------------------------------------------------
 # block application
 # ---------------------------------------------------------------------------
+# Each block's parts run under a ``jax.named_scope`` (``ssm``, ``attn``,
+# ``ffn``; ``embed`` and ``head`` around the trunk), so a profiler trace
+# names the device's operations by the layer they belong to. Scopes are
+# metadata: they change neither the program nor its numbers.
 def _ffn(cfg, bp, x, aux):
-    if cfg.is_moe:
+    if not (cfg.is_moe or cfg.d_ff):
+        return x, aux
+    with jax.named_scope("ffn"):
         h = rms_norm(x, bp["ln2"])
-        y, a = moe_forward(cfg, bp["moe"], h)
-        return x + y, aux + a
-    if cfg.d_ff:
-        h = rms_norm(x, bp["ln2"])
+        if cfg.is_moe:
+            y, a = moe_forward(cfg, bp["moe"], h)
+            return x + y, aux + a
         return x + mlp_forward(bp["mlp"], h), aux
-    return x, aux
 
 
 def _block_fwd(cfg, kind, bp, x, positions, aux, build_cache):
     """Full-sequence application (train / prefill)."""
     cache = None
     if kind == "ssm":
-        h = rms_norm(x, bp["ln"])
-        if build_cache:
-            y, cache = ssm_forward(cfg, bp["ssm"], h, build_cache=True)
-            x = x + y
-        else:
-            x = x + ssm_forward(cfg, bp["ssm"], h)
+        with jax.named_scope("ssm"):
+            h = rms_norm(x, bp["ln"])
+            if build_cache:
+                y, cache = ssm_forward(cfg, bp["ssm"], h, build_cache=True)
+                x = x + y
+            else:
+                x = x + ssm_forward(cfg, bp["ssm"], h)
     else:
-        h = rms_norm(x, bp["ln1"])
-        y, cache = attn_forward(cfg, bp["attn"], h, positions, kind,
-                                build_cache=build_cache)
-        x = x + y
+        with jax.named_scope("attn"):
+            h = rms_norm(x, bp["ln1"])
+            y, cache = attn_forward(cfg, bp["attn"], h, positions, kind,
+                                    build_cache=build_cache)
+            x = x + y
         x, aux = _ffn(cfg, bp, x, aux)
     return x, aux, cache
 
 
 def _block_decode(cfg, kind, bp, x, pos, cache):
     if kind == "ssm":
-        h = rms_norm(x, bp["ln"])
-        y, cache = ssm_decode(cfg, bp["ssm"], h, cache)
-        return x + y, cache, True
-    h = rms_norm(x, bp["ln1"])
-    y, cache = attn_decode(cfg, bp["attn"], h, pos, cache, kind)
-    x = x + y
+        with jax.named_scope("ssm"):
+            h = rms_norm(x, bp["ln"])
+            y, cache = ssm_decode(cfg, bp["ssm"], h, cache)
+            return x + y, cache, True
+    with jax.named_scope("attn"):
+        h = rms_norm(x, bp["ln1"])
+        y, cache = attn_decode(cfg, bp["attn"], h, pos, cache, kind)
+        x = x + y
     x, _ = _ffn(cfg, bp, x, 0.0)
     return x, cache, False
 
@@ -231,6 +239,7 @@ def _stack_decode(cfg, params, x, pos, caches):
 # ---------------------------------------------------------------------------
 # frontends / positions
 # ---------------------------------------------------------------------------
+@jax.named_scope("embed")
 def _embed(cfg, params, inputs):
     cdt = jnp.dtype(cfg.compute_dtype)
     if cfg.frontend == "token":
@@ -264,14 +273,15 @@ def train_loss(cfg, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     b, s = labels.shape
     x = _embed(cfg, params, inputs)
     x, aux, _ = _stack_fwd(cfg, params, x, _positions(cfg, b, s))
-    h = rms_norm(x, params["final_norm"])
-    loss_sum, count = chunked_softmax_xent(
-        h.reshape(-1, cfg.d_model),
-        params["unembed"],
-        labels.reshape(-1),
-        chunk=cfg.loss_chunk,
-        final_softcap=cfg.final_logit_softcap,
-    )
+    with jax.named_scope("head"):
+        h = rms_norm(x, params["final_norm"])
+        loss_sum, count = chunked_softmax_xent(
+            h.reshape(-1, cfg.d_model),
+            params["unembed"],
+            labels.reshape(-1),
+            chunk=cfg.loss_chunk,
+            final_softcap=cfg.final_logit_softcap,
+        )
     loss = loss_sum / jnp.maximum(count, 1.0)
     metrics = {"loss": loss, "tokens": count}
     if cfg.is_moe:
@@ -285,6 +295,12 @@ def _logits(cfg, params, h):
     return soft_cap(out, cfg.final_logit_softcap)
 
 
+@jax.named_scope("head")
+def _head(cfg, params, x):
+    """Final norm and float32 logits."""
+    return _logits(cfg, params, rms_norm(x, params["final_norm"]))
+
+
 def prefill(cfg, params, inputs) -> Tuple[jax.Array, Any, jax.Array]:
     """Full-sequence prefill; returns (last-token logits, caches, pos)."""
     if inputs.ndim == 2:
@@ -294,9 +310,8 @@ def prefill(cfg, params, inputs) -> Tuple[jax.Array, Any, jax.Array]:
     x = _embed(cfg, params, inputs)
     x, _, caches = _stack_fwd(cfg, params, x, _positions(cfg, b, s),
                               build_cache=True)
-    h = rms_norm(x[:, -1:], params["final_norm"])
     pos = jnp.full((b,), s, jnp.int32)
-    return _logits(cfg, params, h)[:, 0], caches, pos
+    return _head(cfg, params, x[:, -1:])[:, 0], caches, pos
 
 
 def init_decode_caches(cfg, batch: int, cache_len: int, filled: bool = False):
@@ -401,5 +416,4 @@ def decode_step(cfg, params, token, pos, caches):
     pos+1)."""
     x = _embed(cfg, params, token)
     x, new_caches = _stack_decode(cfg, params, x, pos, caches)
-    h = rms_norm(x, params["final_norm"])
-    return _logits(cfg, params, h)[:, 0], new_caches, pos + 1
+    return _head(cfg, params, x)[:, 0], new_caches, pos + 1

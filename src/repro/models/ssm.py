@@ -94,19 +94,20 @@ def ssm_forward(cfg, p: Dict[str, jax.Array], h: jax.Array,
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
     from ..kernels.ssd.ref import ssd_reference
 
-    if build_cache:
-        y, state = ssd_reference(
-            x.reshape(bsz, l, nh, hp), dt, a,
-            b.reshape(bsz, l, g, n), c.reshape(bsz, l, g, n),
-            chunk=cfg.ssm_chunk, d_skip=p["d_skip"].astype(jnp.float32),
-            return_final_state=True,
-        )
-    else:
-        y = ssd_ops.ssd(
-            x.reshape(bsz, l, nh, hp), dt, a,
-            b.reshape(bsz, l, g, n), c.reshape(bsz, l, g, n),
-            chunk=cfg.ssm_chunk, d_skip=p["d_skip"].astype(jnp.float32),
-        )
+    with jax.named_scope("state_update"):   # the SSD chunked scan
+        if build_cache:
+            y, state = ssd_reference(
+                x.reshape(bsz, l, nh, hp), dt, a,
+                b.reshape(bsz, l, g, n), c.reshape(bsz, l, g, n),
+                chunk=cfg.ssm_chunk, d_skip=p["d_skip"].astype(jnp.float32),
+                return_final_state=True,
+            )
+        else:
+            y = ssd_ops.ssd(
+                x.reshape(bsz, l, nh, hp), dt, a,
+                b.reshape(bsz, l, g, n), c.reshape(bsz, l, g, n),
+                chunk=cfg.ssm_chunk, d_skip=p["d_skip"].astype(jnp.float32),
+            )
     y = y.reshape(bsz, l, d_in)
     y = rms_norm(y * jax.nn.silu(z), p["norm"])
     out = y @ p["wo"].astype(y.dtype)
@@ -154,15 +155,16 @@ def ssm_decode(
                                           axis=1)
     x, b, c = outs["conv_x"], outs["conv_b"], outs["conv_c"]
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
-    y, state = ssd_decode_step(
-        x[:, 0].reshape(bsz, nh, hp),
-        dt[:, 0],
-        a,
-        b[:, 0].reshape(bsz, g, n),
-        c[:, 0].reshape(bsz, g, n),
-        cache["state"],
-        d_skip=p["d_skip"].astype(jnp.float32),
-    )
+    with jax.named_scope("state_update"):   # the SSD recurrence
+        y, state = ssd_decode_step(
+            x[:, 0].reshape(bsz, nh, hp),
+            dt[:, 0],
+            a,
+            b[:, 0].reshape(bsz, g, n),
+            c[:, 0].reshape(bsz, g, n),
+            cache["state"],
+            d_skip=p["d_skip"].astype(jnp.float32),
+        )
     new_cache["state"] = state
     y = y.reshape(bsz, 1, cfg.ssm_d_inner)
     y = rms_norm(y * jax.nn.silu(z), p["norm"])
