@@ -174,10 +174,12 @@ def ssd_decode_step(
     bb = jnp.repeat(b_t, rep, axis=1).astype(f32)
     cb = jnp.repeat(c_t, rep, axis=1).astype(f32)
     dec = jnp.exp(dt_t.astype(f32) * a.astype(f32))
-    state = state * dec[..., None, None] + jnp.einsum(
-        "bh,bhp,bhn->bhpn", dt_t.astype(f32), x_t.astype(f32), bb
-    )
-    y = jnp.einsum("bhn,bhpn->bhp", cb, state)
+    dtx = dt_t.astype(f32)[..., None] * x_t.astype(f32)            # (B,H,P)
+    # y = C·s_new, reassociated to read only the old state: the new state
+    # is then read by nothing but its write, which can go in place.
+    y = dec[..., None] * jnp.einsum("bhn,bhpn->bhp", cb, state) + dtx * (
+        jnp.einsum("bhn,bhn->bh", cb, bb)[..., None])
+    state = state * dec[..., None, None] + dtx[..., None] * bb[:, :, None, :]
     if d_skip is not None:
         y = y + d_skip.astype(f32)[None, :, None] * x_t.astype(f32)
     return y.astype(x_t.dtype), state

@@ -2,7 +2,7 @@
 
 One generic trunk covers all ten assigned architectures:
 
-  * the layer stack is a ``lax.scan`` over ``cfg.repeats`` repetitions of
+  * the layer stack is a loop over ``cfg.repeats`` repetitions of
     a "super-layer" (``cfg.pattern`` — e.g. ``("attn",)`` for llama,
     ``("attn_local", "attn_global")`` for gemma-2,
     ``("ssm",)*5 + ("shared_attn",)`` for zamba-2) — keeping the HLO
@@ -150,18 +150,40 @@ def _block_fwd(cfg, kind, bp, x, positions, aux, build_cache):
     return x, aux, cache
 
 
-def _block_decode(cfg, kind, bp, x, pos, cache):
+def _row(stack, i):
+    """Row ``i`` of every leaf of a layer-stacked pytree."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, 0, keepdims=False), stack)
+
+
+def _write_rows(stack, old, new, i):
+    """Write a layer's new cache leaves into row ``i`` of the stacked
+    caches; a leaf the block passed through unchanged is not rewritten."""
+    return jax.tree.map(
+        lambda s, o, n: s if n is o else jax.lax.dynamic_update_index_in_dim(
+            s, n, i, 0),
+        stack, old, new,
+    )
+
+
+def _block_decode(cfg, kind, bp, x, pos, stack, i):
+    """Decode one block against row ``i`` of its stacked cache; returns the
+    new activation and the stack with that row updated in place. The row's
+    write-back runs in the block's scope, so the state traffic is named by
+    the layer it belongs to."""
+    cache = _row(stack, i)
     if kind == "ssm":
         with jax.named_scope("ssm"):
             h = rms_norm(x, bp["ln"])
-            y, cache = ssm_decode(cfg, bp["ssm"], h, cache)
-            return x + y, cache, True
+            y, new = ssm_decode(cfg, bp["ssm"], h, cache)
+            return x + y, _write_rows(stack, cache, new, i)
     with jax.named_scope("attn"):
         h = rms_norm(x, bp["ln1"])
-        y, cache = attn_decode(cfg, bp["attn"], h, pos, cache, kind)
+        y, new = attn_decode(cfg, bp["attn"], h, pos, cache, kind)
         x = x + y
+        stack = _write_rows(stack, cache, new, i)
     x, _ = _ffn(cfg, bp, x, 0.0)
-    return x, cache, False
+    return x, stack
 
 
 # ---------------------------------------------------------------------------
@@ -209,31 +231,29 @@ def _stack_fwd(cfg, params, x, positions, build_cache=False):
 
 
 def _stack_decode(cfg, params, x, pos, caches):
+    """The layer loop of a decode step. The stacked caches ride in the
+    loop's carry and each layer updates its own row in place, so with the
+    caches donated the step writes into its input buffers: as a scan's
+    ``xs``/``ys`` they would need a second whole stack and a copy."""
     shared = params.get("shared")
 
-    def body(carry, xs):
-        x = carry
-        slot_rows, cache_rows = xs
-        new_caches = {}
-        for i, kind in enumerate(cfg.pattern):
-            key = f"slot{i}"
-            bp = shared if kind == "shared_attn" else slot_rows[key]
-            x, new_c, _ = _block_decode(cfg, kind, bp, x, pos,
-                                        cache_rows[key])
-            new_caches[key] = new_c
-        return x, new_caches
+    def body(i, carry):
+        x, caches = carry
+        caches = dict(caches)
+        for j, kind in enumerate(cfg.pattern):
+            key = f"slot{j}"
+            bp = (shared if kind == "shared_attn"
+                  else _row(params["slots"][key], i))
+            x, caches[key] = _block_decode(cfg, kind, bp, x, pos,
+                                           caches[key], i)
+        return x, caches
 
     if getattr(cfg, "scan_layers", True):
-        x, new_caches = jax.lax.scan(body, x, (params["slots"], caches))
-        return x, new_caches
-    cache_rows_out = []
+        return jax.lax.fori_loop(0, cfg.repeats, body, (x, caches))
+    carry = (x, caches)
     for r in range(cfg.repeats):
-        rows = jax.tree.map(lambda a: a[r], params["slots"])
-        cache_r = jax.tree.map(lambda a: a[r], caches)
-        x, new_c = body(x, (rows, cache_r))
-        cache_rows_out.append(new_c)
-    new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *cache_rows_out)
-    return x, new_caches
+        carry = body(r, carry)
+    return carry
 
 
 # ---------------------------------------------------------------------------

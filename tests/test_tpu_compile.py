@@ -1,8 +1,11 @@
 """The main path's Pallas kernels compile for a TPU v5e at the widths the
-models use. The chip is described, not attached: these compiles find
-block layouts and memory use the chip's compiler refuses, which
-interpret-mode tests cannot see. They run nothing and time nothing.
+models use, and the serving step updates its caches in place there. The
+chip is described, not attached: these compiles find block layouts and
+memory use the chip's compiler refuses, which interpret-mode tests cannot
+see. They run nothing and time nothing.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +15,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.flash_attention.kernel import flash_attention
 from repro.kernels.ssd.kernel import ssd_pallas
+from repro.launch.steps import make_serve_step, serve_params_shapes
+from repro.models import lm
 
 
 @pytest.fixture(scope="module")
@@ -66,3 +71,32 @@ def test_ssd_compiles_at_published_widths(arch, one_chip):
          ((h,), jnp.float32)],
         one_chip,
     )
+
+
+# (arch, batch, most temporary bytes): one layer's f32 SSM state for
+# mamba2-130m at batch 256 (256 x 24 x 64 x 128 x 4 B); a stacked cache
+# threaded through the layer loop as its input and output would need a
+# second whole stack (4.9 GB and 2.1 GB).
+@pytest.mark.parametrize("arch,batch,most", [
+    ("mamba2-130m", 256, 201_326_592),
+    ("zamba2-2.7b", 16, 100_000_000),
+])
+def test_serve_step_updates_caches_in_place(arch, batch, most, one_chip):
+    cfg = get_config(arch)
+    caches = jax.eval_shape(
+        lambda: lm.init_decode_caches(cfg, batch, cache_len=2048))
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    step = jax.jit(make_serve_step(cfg), donate_argnums=3).lower(
+        spec(serve_params_shapes(cfg)),
+        jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip),
+        spec(caches)).compile()
+    assert step.memory_analysis().temp_size_in_bytes < most
+    stacks = {"f32[%s]" % ",".join(map(str, a.shape))
+              for a in jax.tree.leaves(caches) if a.dtype == jnp.float32}
+    copied = re.findall(r"= (\S+?)\{[^}]*\} copy\(", step.as_text())
+    assert not stacks & set(copied)
