@@ -5,7 +5,7 @@ Four phases in one process, at published widths with random weights:
 * train: ``repro.launch.train.train`` on mamba2-130m (all 24 layers,
   50,280-token vocabulary), batch 8 x 2048 tokens, 4 steps;
 * serve: ``repro.launch.serve.serve`` on zamba2-2.7b (all 54 layers with
-  the shared attention block), 8 requests of 1024 prompt tokens and 160
+  its two shared attention blocks), 8 requests of 1024 prompt tokens and 160
   generated tokens, past the 128-slot hot ring, as deployed (bf16); then
   once more with float32 activations and highest-precision matmuls,
   where the last decode step must match one prefill over the prompt plus

@@ -23,6 +23,7 @@ from . import (  # noqa: F401
     qwen3_moe_235b_a22b,
     starcoder2_15b,
     zamba2_2_7b,
+    zamba2_7b,
 )
 
 ALL_ARCHS = list_configs()

@@ -42,7 +42,7 @@ class ModelConfig:
     vocab_size: int = 0
     # layer pattern: tuple of block kinds forming one scan "super-layer";
     # repeated num_layers // len(pattern) times.
-    pattern: Tuple[str, ...] = ("attn",)   # attn | attn_local | attn_global | ssm | shared_attn
+    pattern: Tuple[str, ...] = ("attn",)   # attn | attn_local | attn_global | ssm
     # attention features
     window: Optional[int] = None       # sliding-window size (SWA / local layers)
     attn_logit_softcap: Optional[float] = None
@@ -64,6 +64,15 @@ class ModelConfig:
     ssm_chunk: int = 256
     ssm_conv: int = 4
     ssm_groups: int = 1
+    # Zamba2 hybrid: before each Mamba layer listed in hybrid_layer_ids
+    # one of num_mem_blocks shared attention + GELU MLP blocks (alternating)
+    # runs over [x, embedding] (2 x d_model wide), with a LoRA of rank
+    # adapter_rank on its MLP and a d_model x d_model projection into the
+    # Mamba layer's input, both per invocation (pattern is ("ssm",))
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
+    norm_eps: float = 1e-6             # every RMSNorm's epsilon
     # frontend: "token" (embedding table) or "embed" (precomputed
     # patch/frame embeddings — VLM/audio stub per assignment)
     frontend: str = "token"
@@ -110,6 +119,19 @@ class ModelConfig:
         return self.ssm_d_inner // self.ssm_head_dim
 
     @property
+    def attn_in_dim(self) -> int:
+        """Width of the attention input: [x, embedding] in a hybrid."""
+        return 2 * self.d_model if self.hybrid_layer_ids else self.d_model
+
+    @property
+    def attn_scale(self) -> Optional[float]:
+        """Score scale where it is not head_dim ** -0.5: a hybrid's heads span
+        [x, embedding], and it scales by the half that one of them takes."""
+        if self.hybrid_layer_ids:
+            return (self.resolved_head_dim / 2) ** -0.5
+        return None
+
+    @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
@@ -118,16 +140,16 @@ class ModelConfig:
         return max(self.num_experts, self.moe_pad_experts_to)
 
     def n_params(self) -> int:
-        """Approximate parameter count (for 6ND model-FLOPs accounting)."""
+        """Parameter count (for 6ND model-FLOPs accounting); exact for the
+        SSM and hybrid trunks."""
         m, v = self.d_model, self.padded_vocab
         total = 0
         if self.frontend == "token":
             total += v * m
         total += v * m  # unembed
         hd = self.resolved_head_dim
-        per_kind: Dict[str, int] = {}
-        attn = m * (self.num_heads * hd) + 2 * m * (self.num_kv_heads * hd) \
-            + (self.num_heads * hd) * m
+        q, kv = self.num_heads * hd, self.num_kv_heads * hd
+        attn = self.attn_in_dim * (q + 2 * kv) + q * m
         dense_ffn = 3 * m * self.d_ff if self.d_ff else 0
         moe_ffn = (
             self.moe_experts_physical * 3 * m * self.moe_d_ff
@@ -136,29 +158,29 @@ class ModelConfig:
             else 0
         )
         ffn = moe_ffn if self.is_moe else dense_ffn
+        per_kind: Dict[str, int] = {}
         per_kind["attn"] = attn + ffn + 2 * m
         per_kind["attn_local"] = per_kind["attn"]
         per_kind["attn_global"] = per_kind["attn"]
-        per_kind["shared_attn"] = per_kind["attn"]  # counted once below
         d_in = self.ssm_d_inner
-        n, h = self.ssm_state, self.ssm_heads
+        gn, h = self.ssm_groups * self.ssm_state, self.ssm_heads
         per_kind["ssm"] = (
             m * d_in * 2                      # Wz, Wx
-            + 2 * m * (self.ssm_groups * n)   # WB, WC
+            + 2 * m * gn                      # WB, WC
             + m * h                           # Wdt
             + d_in * m                        # out
-            + 2 * m                           # norms
+            + self.ssm_conv * (d_in + 2 * gn)  # conv
+            + 3 * h + d_in                    # dt_bias, a_log, D, gated norm
+            + m                               # pre-norm
         )
-        shared_seen = False
-        for r in range(self.repeats):
-            for kind in self.pattern:
-                if kind == "shared_attn":
-                    if not shared_seen:
-                        total += per_kind["shared_attn"]
-                        shared_seen = True
-                else:
-                    total += per_kind[kind]
-        return total
+        total += self.repeats * sum(per_kind[k] for k in self.pattern)
+        if self.hybrid_layer_ids:
+            f, r = self.d_ff, self.adapter_rank
+            shared = attn + 3 * m * f + self.attn_in_dim + m   # + 2 norms
+            per_call = m * r + r * 2 * f + m * m     # LoRA A, B; projection
+            total += (self.num_mem_blocks * shared
+                      + len(self.hybrid_layer_ids) * per_call)
+        return total + m  # final norm
 
     def n_active_params(self) -> int:
         """Active params per token (MoE: top-k experts only)."""
@@ -245,6 +267,11 @@ def smoke_config(name: str) -> ModelConfig:
         # preserve GQA grouping where possible
         ratio = max(1, cfg.num_heads // max(1, cfg.num_kv_heads))
         updates["num_kv_heads"] = max(1, nh // min(ratio, nh))
+    if cfg.hybrid_layer_ids:
+        # shared blocks A, B, A before layers 1, 3 and 4, one Mamba layer
+        # after the last: each block serves with its own adapters
+        updates.update(num_layers=6, hybrid_layer_ids=(1, 3, 4),
+                       adapter_rank=8)
     if cfg.is_moe:
         # capacity_factor 8 ⇒ no token drops at smoke scale, making
         # outputs batch-context-invariant (prefill/decode comparable)

@@ -29,6 +29,8 @@ __all__ = [
     "attn_forward",
     "init_kv_cache",
     "attn_decode",
+    "decode_qkv",
+    "decode_attend",
     "chunked_attention",
 ]
 
@@ -36,28 +38,30 @@ NEG_INF = -1e30
 
 
 def init_attn_params(key, cfg) -> Dict[str, jax.Array]:
-    m = cfg.d_model
+    """q/k/v read ``cfg.attn_in_dim`` wide inputs; the output is d_model."""
+    m, m_in = cfg.d_model, cfg.attn_in_dim
     hd = cfg.resolved_head_dim
     h, k = cfg.num_heads, cfg.num_kv_heads
     dtype = jnp.dtype(cfg.param_dtype)
     keys = jax.random.split(key, 4)
     return {
-        "wq": truncated_normal(keys[0], (m, h * hd), 1.0, dtype),
-        "wk": truncated_normal(keys[1], (m, k * hd), 1.0, dtype),
-        "wv": truncated_normal(keys[2], (m, k * hd), 1.0, dtype),
+        "wq": truncated_normal(keys[0], (m_in, h * hd), 1.0, dtype),
+        "wk": truncated_normal(keys[1], (m_in, k * hd), 1.0, dtype),
+        "wv": truncated_normal(keys[2], (m_in, k * hd), 1.0, dtype),
         "wo": truncated_normal(keys[3], (h * hd, m), 1.0, dtype),
     }
 
 
-def _project_qkv(cfg, p, h):
+def _project_qkv(cfg, p, h, barrier=False):
     b, s, _ = h.shape
     hd = cfg.resolved_head_dim
     nh, nk = cfg.num_heads, cfg.num_kv_heads
     cdt = h.dtype
-    q = (h @ p["wq"].astype(cdt)).reshape(b, s, nh, hd)
-    k = (h @ p["wk"].astype(cdt)).reshape(b, s, nk, hd)
-    v = (h @ p["wv"].astype(cdt)).reshape(b, s, nk, hd)
-    return q, k, v
+    q, k, v = (h @ p[w].astype(cdt) for w in ("wq", "wk", "wv"))
+    if barrier:
+        q, k, v = jax.lax.optimization_barrier((q, k, v))
+    return (q.reshape(b, s, nh, hd), k.reshape(b, s, nk, hd),
+            v.reshape(b, s, nk, hd))
 
 
 def _rope(cfg, x, positions):
@@ -80,8 +84,10 @@ def attention_parts(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     kv_chunk: int = 1024,
+    scale: Optional[float] = None,
 ):
     """Unnormalized online-softmax accumulation over one KV source.
+    Scores are scaled by ``scale`` (``D ** -0.5`` where None).
 
     Returns (m, l, acc): running max (B,S,K,G), denominator and fp32
     accumulator (B,S,K,G,D). Multiple sources (e.g. a frozen prefix
@@ -94,7 +100,8 @@ def attention_parts(
     # Keep q/k/v in compute dtype across any resharding boundary — the
     # MXU takes bf16 inputs with fp32 accumulation, and casting early
     # doubles the SP all-gather bytes (§Perf iter C1).
-    qr = q.reshape(b, s, nk, g, d) * jnp.asarray(d ** -0.5, q.dtype)
+    qr = q.reshape(b, s, nk, g, d) * jnp.asarray(
+        d ** -0.5 if scale is None else scale, q.dtype)
     kv_chunk = min(kv_chunk, t)
     if t % kv_chunk != 0:
         pad = kv_chunk - t % kv_chunk
@@ -168,11 +175,13 @@ def chunked_attention(
     window: Optional[int] = None,
     softcap: Optional[float] = None,
     kv_chunk: int = 1024,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """Causal online-softmax attention, scanning KV in chunks."""
     b, s, h, d = q.shape
     m, l, acc = attention_parts(q, k, v, q_pos, kv_pos, window=window,
-                                softcap=softcap, kv_chunk=kv_chunk)
+                                softcap=softcap, kv_chunk=kv_chunk,
+                                scale=scale)
     out = acc / jnp.maximum(l, 1e-30)[..., None]
     return out.reshape(b, s, h, d).astype(q.dtype)
 
@@ -204,6 +213,7 @@ def attn_forward(
         window=window,
         softcap=cfg.attn_logit_softcap,
         kv_chunk=cfg.attn_kv_chunk,
+        scale=cfg.attn_scale,
     )
     b, s, _, _ = out.shape
     y = out.reshape(b, s, -1) @ p["wo"].astype(out.dtype)
@@ -261,6 +271,42 @@ def _ring_write(cache_arr, new, idx):
     return jax.vmap(upd)(cache_arr, new, idx)
 
 
+def decode_qkv(cfg, p, x, pos):
+    """q, k, v of one token (B, 1, M), rotated to its position. A head
+    size off the 128-lane tile (Zamba2's 224) makes the TPU compiler lay
+    the q/k/v weights out transposed to split the products into heads, a
+    copy of each weight at every step; a barrier on the products keeps
+    their own layout and moves the split to them."""
+    b = x.shape[0]
+    if cfg.mrope_sections is not None:
+        positions = jnp.broadcast_to(pos[None, :, None], (3, b, 1))
+    else:
+        positions = pos[:, None]
+    q, k_new, v_new = _project_qkv(cfg, p, x,
+                                   barrier=cfg.resolved_head_dim % 128 != 0)
+    return _rope(cfg, q, positions), _rope(cfg, k_new, positions), v_new
+
+
+def decode_attend(cfg, p, q, pos, sources, kind):
+    """One token's attention over ``(k, v, kv_pos)`` sources, combined
+    exactly (flash-decoding split), and the output projection."""
+    b = q.shape[0]
+    window = cfg.window if kind in ("attn_local",) or (
+        kind == "attn" and cfg.window is not None
+    ) else None
+    kw = dict(window=window, softcap=cfg.attn_logit_softcap,
+              scale=cfg.attn_scale)
+    # Single-shot (kv_chunk = full length): chunking would reshape the
+    # sequence-sharded prefix and force XLA to all-gather it; unreshaped,
+    # the q·K / softmax / p·V reductions over the sharded axis lower to
+    # tiny per-stat all-reduces instead of cache movement.
+    parts = [attention_parts(q, k, v, pos[:, None], kv_pos,
+                             kv_chunk=k.shape[1], **kw)
+             for k, v, kv_pos in sources]
+    out = combine_parts(parts, (b, 1, q.shape[2], q.shape[3]), q.dtype)
+    return out.reshape(b, 1, -1) @ p["wo"].astype(out.dtype)
+
+
 def attn_decode(
     cfg,
     p: Dict[str, jax.Array],
@@ -271,14 +317,7 @@ def attn_decode(
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """One-token decode: write to the hot ring, read prefix + hot ring,
     combine the two partial softmaxes exactly (flash-decoding split)."""
-    b = x.shape[0]
-    if cfg.mrope_sections is not None:
-        positions = jnp.broadcast_to(pos[None, :, None], (3, b, 1))
-    else:
-        positions = pos[:, None]
-    q, k_new, v_new = _project_qkv(cfg, p, x)
-    q = _rope(cfg, q, positions)
-    k_new = _rope(cfg, k_new, positions)
+    q, k_new, v_new = decode_qkv(cfg, p, x, pos)
     hot = cache["hk"].shape[1]
     slot = (pos % hot).astype(jnp.int32)
     cache = dict(cache)
@@ -286,24 +325,8 @@ def attn_decode(
     cache["hv"] = _ring_write(cache["hv"], v_new.astype(cache["hv"].dtype), slot)
     cache["h_pos"] = _ring_write(cache["h_pos"],
                                  pos[:, None].astype(jnp.int32), slot)
-    window = cfg.window if kind in ("attn_local",) or (
-        kind == "attn" and cfg.window is not None
-    ) else None
-    kw = dict(window=window, softcap=cfg.attn_logit_softcap)
-    # Single-shot (kv_chunk = full length): chunking would reshape the
-    # sequence-sharded prefix and force XLA to all-gather it; unreshaped,
-    # the q·K / softmax / p·V reductions over the sharded axis lower to
-    # tiny per-stat all-reduces instead of cache movement.
-    parts = [
-        attention_parts(
-            q, cache["k"], cache["v"], pos[:, None], cache["kv_pos"],
-            kv_chunk=cache["k"].shape[1], **kw,
-        ),
-        attention_parts(
-            q, cache["hk"], cache["hv"], pos[:, None], cache["h_pos"],
-            kv_chunk=hot, **kw,
-        ),
-    ]
-    out = combine_parts(parts, (b, 1, q.shape[2], q.shape[3]), q.dtype)
-    y = out.reshape(b, 1, -1) @ p["wo"].astype(out.dtype)
+    y = decode_attend(cfg, p, q, pos, [
+        (cache["k"], cache["v"], cache["kv_pos"]),
+        (cache["hk"], cache["hv"], cache["h_pos"]),
+    ], kind)
     return y, cache

@@ -4,12 +4,15 @@ One generic trunk covers all ten assigned architectures:
 
   * the layer stack is a loop over ``cfg.repeats`` repetitions of
     a "super-layer" (``cfg.pattern`` — e.g. ``("attn",)`` for llama,
-    ``("attn_local", "attn_global")`` for gemma-2,
-    ``("ssm",)*5 + ("shared_attn",)`` for zamba-2) — keeping the HLO
-    O(1) in depth and the live activation set bounded (remat policy per
-    config);
-  * ``shared_attn`` blocks share one parameter set across all scan
-    repetitions (Zamba-2) while carrying per-repetition KV caches;
+    ``("attn_local", "attn_global")`` for gemma-2, ``("ssm",)`` for
+    mamba-2 and zamba-2) — keeping the HLO O(1) in depth and the live
+    activation set bounded (remat policy per config);
+  * Zamba-2 (``cfg.hybrid_layer_ids``): before each listed Mamba layer
+    one of ``cfg.num_mem_blocks`` shared attention + MLP blocks runs
+    over [x, embedding]; its output, through a per-invocation LoRA'd MLP
+    and projection, is added to that layer's input. Its layers are
+    unrolled (see ``_hybrid_fwd``); each invocation keeps its own KV
+    cache (the ``hybrid`` stack of the caches);
   * frontends: ``token`` (embedding table) or ``embed`` (precomputed
     patch/frame embeddings — the VLM/audio stub per the assignment);
   * losses use chunked cross-entropy (never materializes the full
@@ -32,11 +35,18 @@ from ..sharding.act_sharding import constrain
 from .attention import (
     attn_decode,
     attn_forward,
+    decode_attend,
+    decode_qkv,
     init_attn_params,
     init_kv_cache,
 )
 from .common import chunked_softmax_xent, rms_norm, soft_cap, truncated_normal
-from .mlp import init_mlp_params, mlp_forward
+from .mlp import (
+    init_lora_mlp_params,
+    init_mlp_params,
+    lora_mlp_forward,
+    mlp_forward,
+)
 from .moe import init_moe_params, moe_forward
 from .ssm import init_ssm_cache, init_ssm_params, ssm_decode, ssm_forward
 
@@ -53,7 +63,15 @@ MOE_AUX_WEIGHT = 0.01
 
 
 def _is_attn(kind: str) -> bool:
-    return kind in ("attn", "attn_local", "attn_global", "shared_attn")
+    return kind in ("attn", "attn_local", "attn_global")
+
+
+def _cache_kinds(cfg):
+    """``(key, kind)`` of each stack of the decode caches."""
+    out = [(f"slot{i}", kind) for i, kind in enumerate(cfg.pattern)]
+    if cfg.hybrid_layer_ids:
+        out.append(("hybrid", "attn"))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -80,6 +98,31 @@ def _init_block(cfg, kind: str, key) -> Dict[str, Any]:
     return p
 
 
+def _init_shared(cfg, key) -> Dict[str, Any]:
+    """One shared block: attention over [x, embedding], then its MLP."""
+    dtype = jnp.dtype(cfg.param_dtype)
+    k1, k2 = jax.random.split(key)
+    return {
+        "ln1": jnp.zeros((cfg.attn_in_dim,), dtype),
+        "attn": init_attn_params(k1, cfg),
+        "ln2": jnp.zeros((cfg.d_model,), dtype),
+        "mlp": init_lora_mlp_params(k2, cfg),
+    }
+
+
+def _init_invocation(cfg, key) -> Dict[str, Any]:
+    """One invocation's own weights: its MLP's LoRA (A, B) and the
+    projection of the block's output into the Mamba layer's input."""
+    m, r = cfg.d_model, cfg.adapter_rank
+    dtype = jnp.dtype(cfg.param_dtype)
+    k1, k2, k3 = jax.random.split(key, 3)
+    return {
+        "lora_a": truncated_normal(k1, (m, r), 1.0, dtype),
+        "lora_b": truncated_normal(k2, (r, 2 * cfg.d_ff), 0.1, dtype),
+        "proj": truncated_normal(k3, (m, m), 1.0, dtype),
+    }
+
+
 def init_params(cfg, key) -> Dict[str, Any]:
     keys = jax.random.split(key, len(cfg.pattern) + 4)
     params: Dict[str, Any] = {}
@@ -90,15 +133,17 @@ def init_params(cfg, key) -> Dict[str, Any]:
         )
     slots: Dict[str, Any] = {}
     for i, kind in enumerate(cfg.pattern):
-        if kind == "shared_attn":
-            continue
         rkeys = jax.random.split(keys[i], cfg.repeats)
         slots[f"slot{i}"] = jax.vmap(
             functools.partial(_init_block, cfg, kind)
         )(rkeys)
     params["slots"] = slots
-    if "shared_attn" in cfg.pattern:
-        params["shared"] = _init_block(cfg, "shared_attn", keys[-2])
+    if cfg.hybrid_layer_ids:
+        k_shared, k_calls = jax.random.split(keys[-2])
+        params["shared"] = jax.vmap(functools.partial(_init_shared, cfg))(
+            jax.random.split(k_shared, cfg.num_mem_blocks))
+        params["hybrid"] = jax.vmap(functools.partial(_init_invocation, cfg))(
+            jax.random.split(k_calls, len(cfg.hybrid_layer_ids)))
     params["final_norm"] = jnp.zeros((cfg.d_model,), jnp.dtype(cfg.param_dtype))
     params["unembed"] = truncated_normal(
         keys[-3], (cfg.d_model, cfg.padded_vocab), 1.0,
@@ -122,19 +167,20 @@ def _ffn(cfg, bp, x, aux):
     if not (cfg.is_moe or cfg.d_ff):
         return x, aux
     with jax.named_scope("ffn"):
-        h = rms_norm(x, bp["ln2"])
+        h = rms_norm(x, bp["ln2"], cfg.norm_eps)
         if cfg.is_moe:
             y, a = moe_forward(cfg, bp["moe"], h)
             return x + y, aux + a
         return x + mlp_forward(bp["mlp"], h), aux
 
 
-def _block_fwd(cfg, kind, bp, x, positions, aux, build_cache):
-    """Full-sequence application (train / prefill)."""
+def _block_fwd(cfg, kind, bp, x, positions, aux, build_cache, pre=None):
+    """Full-sequence application (train / prefill). ``pre`` (a shared
+    block's output) is added to an SSM block's input, not its residual."""
     cache = None
     if kind == "ssm":
         with jax.named_scope("ssm"):
-            h = rms_norm(x, bp["ln"])
+            h = rms_norm(x if pre is None else x + pre, bp["ln"], cfg.norm_eps)
             if build_cache:
                 y, cache = ssm_forward(cfg, bp["ssm"], h, build_cache=True)
                 x = x + y
@@ -142,7 +188,7 @@ def _block_fwd(cfg, kind, bp, x, positions, aux, build_cache):
                 x = x + ssm_forward(cfg, bp["ssm"], h)
     else:
         with jax.named_scope("attn"):
-            h = rms_norm(x, bp["ln1"])
+            h = rms_norm(x, bp["ln1"], cfg.norm_eps)
             y, cache = attn_forward(cfg, bp["attn"], h, positions, kind,
                                     build_cache=build_cache)
             x = x + y
@@ -166,19 +212,19 @@ def _write_rows(stack, old, new, i):
     )
 
 
-def _block_decode(cfg, kind, bp, x, pos, stack, i):
+def _block_decode(cfg, kind, bp, x, pos, stack, i, pre=None):
     """Decode one block against row ``i`` of its stacked cache; returns the
     new activation and the stack with that row updated in place. The row's
     write-back runs in the block's scope, so the state traffic is named by
-    the layer it belongs to."""
+    the layer it belongs to. ``pre`` as in :func:`_block_fwd`."""
     cache = _row(stack, i)
     if kind == "ssm":
         with jax.named_scope("ssm"):
-            h = rms_norm(x, bp["ln"])
+            h = rms_norm(x if pre is None else x + pre, bp["ln"], cfg.norm_eps)
             y, new = ssm_decode(cfg, bp["ssm"], h, cache)
             return x + y, _write_rows(stack, cache, new, i)
     with jax.named_scope("attn"):
-        h = rms_norm(x, bp["ln1"])
+        h = rms_norm(x, bp["ln1"], cfg.norm_eps)
         y, new = attn_decode(cfg, bp["attn"], h, pos, cache, kind)
         x = x + y
         stack = _write_rows(stack, cache, new, i)
@@ -187,10 +233,72 @@ def _block_decode(cfg, kind, bp, x, pos, stack, i):
 
 
 # ---------------------------------------------------------------------------
+# shared blocks (Zamba-2)
+# ---------------------------------------------------------------------------
+# The attention scope holds the concatenation, input norm, q/k/v, RoPE,
+# the KV write, scores, o-projection and the invocation's projection; the
+# ffn scope the pre-FF norm, gate-up with its LoRA, the gate and down.
+def _shared_mlp_proj(cfg, sp, hp, a):
+    with jax.named_scope("ffn"):
+        f = lora_mlp_forward(sp["mlp"], hp["lora_a"], hp["lora_b"],
+                             rms_norm(a, sp["ln2"], cfg.norm_eps))
+    with jax.named_scope("attn"):
+        return f @ hp["proj"].astype(f.dtype)
+
+
+def _shared_fwd(cfg, params, j, x, e, positions, build_cache):
+    """Invocation ``j`` over the whole sequence: its output (added to the
+    next Mamba layer's input) and its KV cache."""
+    sp = _row(params["shared"], j % cfg.num_mem_blocks)
+    hp = _row(params["hybrid"], j)
+    with jax.named_scope("attn"):
+        u = rms_norm(jnp.concatenate([x, e], -1), sp["ln1"], cfg.norm_eps)
+        a, cache = attn_forward(cfg, sp["attn"], u, positions, "attn",
+                                build_cache=build_cache)
+    return _shared_mlp_proj(cfg, sp, hp, a), cache
+
+
+def _shared_decode(cfg, params, j, x, e, pos, kv):
+    """Invocation ``j`` for one token: its key and value go into row ``j``
+    of the stacked hot rings in place, then it attends over that row (as
+    ``attn_decode`` does over a cache of its own)."""
+    sp = _row(params["shared"], j % cfg.num_mem_blocks)
+    hp = _row(params["hybrid"], j)
+    with jax.named_scope("attn"):
+        u = rms_norm(jnp.concatenate([x, e], -1), sp["ln1"], cfg.norm_eps)
+        q, k, v = decode_qkv(cfg, sp["attn"], u, pos)
+        kv = _write_token(kv, j, k, v, pos)
+        c = _row(kv, j)
+        a = decode_attend(cfg, sp["attn"], q, pos, [
+            (c["k"], c["v"], c["kv_pos"]), (c["hk"], c["hv"], c["h_pos"])],
+            "attn")
+    return _shared_mlp_proj(cfg, sp, hp, a), kv
+
+
+def _write_token(kv, j, k, v, pos):
+    """Write one token's keys and values (B, 1, K, D) into row ``j`` of the
+    stacked hot rings at slot ``pos % decode_hot_len``, one request at a
+    time: written at once (a scatter), the compiler copies the rings."""
+    slot = (pos % kv["hk"].shape[2]).astype(jnp.int32)
+    k, v = k.astype(kv["hk"].dtype), v.astype(kv["hv"].dtype)
+    kv = dict(kv)
+    for r in range(pos.shape[0]):
+        at = (j, r, slot[r])
+        kv["hk"] = jax.lax.dynamic_update_slice(kv["hk"], k[None, r:r + 1],
+                                                at + (0, 0))
+        kv["hv"] = jax.lax.dynamic_update_slice(kv["hv"], v[None, r:r + 1],
+                                                at + (0, 0))
+        kv["h_pos"] = jax.lax.dynamic_update_slice(
+            kv["h_pos"], pos[None, r:r + 1, None].astype(jnp.int32), at)
+    return kv
+
+
+# ---------------------------------------------------------------------------
 # stack (scan over repeats)
 # ---------------------------------------------------------------------------
 def _stack_fwd(cfg, params, x, positions, build_cache=False):
-    shared = params.get("shared")
+    if cfg.hybrid_layer_ids:
+        return _hybrid_fwd(cfg, params, x, positions, build_cache)
 
     def body(carry, xs):
         x, aux = carry
@@ -198,9 +306,8 @@ def _stack_fwd(cfg, params, x, positions, build_cache=False):
         caches = {}
         x = constrain(x)   # layer-boundary activation sharding (SP)
         for i, kind in enumerate(cfg.pattern):
-            bp = shared if kind == "shared_attn" else slot_rows[f"slot{i}"]
-            x, aux, cache = _block_fwd(cfg, kind, bp, x, positions, aux,
-                                       build_cache)
+            x, aux, cache = _block_fwd(cfg, kind, slot_rows[f"slot{i}"], x,
+                                       positions, aux, build_cache)
             if build_cache and cache is not None:
                 caches[f"slot{i}"] = cache
         x = constrain(x)
@@ -235,17 +342,17 @@ def _stack_decode(cfg, params, x, pos, caches):
     loop's carry and each layer updates its own row in place, so with the
     caches donated the step writes into its input buffers: as a scan's
     ``xs``/``ys`` they would need a second whole stack and a copy."""
-    shared = params.get("shared")
+    if cfg.hybrid_layer_ids:
+        return _hybrid_decode(cfg, params, x, pos, caches)
 
     def body(i, carry):
         x, caches = carry
         caches = dict(caches)
         for j, kind in enumerate(cfg.pattern):
             key = f"slot{j}"
-            bp = (shared if kind == "shared_attn"
-                  else _row(params["slots"][key], i))
-            x, caches[key] = _block_decode(cfg, kind, bp, x, pos,
-                                           caches[key], i)
+            x, caches[key] = _block_decode(
+                cfg, kind, _row(params["slots"][key], i), x, pos,
+                caches[key], i)
         return x, caches
 
     if getattr(cfg, "scan_layers", True):
@@ -254,6 +361,56 @@ def _stack_decode(cfg, params, x, pos, caches):
     for r in range(cfg.repeats):
         carry = body(r, carry)
     return carry
+
+
+# Zamba-2's layers are unrolled, each invocation of a shared block before
+# its layer, reading weights and cache rows at static indices. Split into
+# loops at the invocations, or with them inside one loop (under a
+# conditional or a nested loop), the compiler lays the state and KV stacks
+# out inside otherwise than the step's arguments, and copies them whole at
+# every step. ``e`` is the embedding, carried to every invocation.
+def _hybrid_fwd(cfg, params, x, positions, build_cache):
+    e, aux = x, jnp.float32(0.0)
+    ids = list(cfg.hybrid_layer_ids)
+    layers = params["slots"]["slot0"]
+
+    def layer(x, i):
+        t = kv = None
+        if i in ids:
+            t, kv = _shared_fwd(cfg, params, ids.index(i), x, e, positions,
+                                build_cache)
+        x, _, cache = _block_fwd(cfg, "ssm", _row(layers, i), x, positions,
+                                 aux, build_cache, pre=t)
+        return constrain(x), cache, kv
+
+    if cfg.remat == "full":
+        layer = jax.checkpoint(layer, static_argnums=(1,))
+    ssm_caches, kv_caches = [], []
+    for i in range(cfg.repeats):
+        x, cache, kv = layer(constrain(x), i)
+        ssm_caches.append(cache)
+        if kv is not None:
+            kv_caches.append(kv)
+    if not build_cache:
+        return x, aux, None
+
+    def stack(caches):
+        return jax.tree.map(lambda *a: jnp.stack(a), *caches)
+
+    return x, aux, {"slot0": stack(ssm_caches), "hybrid": stack(kv_caches)}
+
+
+def _hybrid_decode(cfg, params, x, pos, caches):
+    e = x
+    ids = list(cfg.hybrid_layer_ids)
+    state, kv = caches["slot0"], caches["hybrid"]
+    for i in range(cfg.repeats):
+        t = None
+        if i in ids:
+            t, kv = _shared_decode(cfg, params, ids.index(i), x, e, pos, kv)
+        x, state = _block_decode(cfg, "ssm", _row(params["slots"]["slot0"], i),
+                                 x, pos, state, i, pre=t)
+    return x, {"slot0": state, "hybrid": kv}
 
 
 # ---------------------------------------------------------------------------
@@ -294,7 +451,7 @@ def train_loss(cfg, params, batch) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     x = _embed(cfg, params, inputs)
     x, aux, _ = _stack_fwd(cfg, params, x, _positions(cfg, b, s))
     with jax.named_scope("head"):
-        h = rms_norm(x, params["final_norm"])
+        h = rms_norm(x, params["final_norm"], cfg.norm_eps)
         loss_sum, count = chunked_softmax_xent(
             h.reshape(-1, cfg.d_model),
             params["unembed"],
@@ -318,7 +475,8 @@ def _logits(cfg, params, h):
 @jax.named_scope("head")
 def _head(cfg, params, x):
     """Final norm and float32 logits."""
-    return _logits(cfg, params, rms_norm(x, params["final_norm"]))
+    return _logits(cfg, params, rms_norm(x, params["final_norm"],
+                                         cfg.norm_eps))
 
 
 def prefill(cfg, params, inputs) -> Tuple[jax.Array, Any, jax.Array]:
@@ -342,15 +500,15 @@ def init_decode_caches(cfg, batch: int, cache_len: int, filled: bool = False):
     shapes use this.
     """
     caches: Dict[str, Any] = {}
-    r = cfg.repeats
     dtype = jnp.dtype(cfg.compute_dtype)
 
-    def stack(tree):
+    def stack(tree, r):
         return jax.tree.map(lambda x: jnp.broadcast_to(x, (r,) + x.shape), tree)
 
-    for i, kind in enumerate(cfg.pattern):
+    for key, kind in _cache_kinds(cfg):
+        r = len(cfg.hybrid_layer_ids) if key == "hybrid" else cfg.repeats
         if kind == "ssm":
-            caches[f"slot{i}"] = stack(init_ssm_cache(cfg, batch, dtype))
+            caches[key] = stack(init_ssm_cache(cfg, batch, dtype), r)
         elif _is_attn(kind):
             c = init_kv_cache(cfg, batch, cache_len, kind, dtype)
             if filled:
@@ -359,7 +517,7 @@ def init_decode_caches(cfg, batch: int, cache_len: int, filled: bool = False):
                     jnp.arange(cache_len - t, cache_len, dtype=jnp.int32),
                     (batch, t),
                 )
-            caches[f"slot{i}"] = stack(c)
+            caches[key] = stack(c, r)
     return caches
 
 
@@ -368,8 +526,7 @@ def grow_caches(cfg, caches, new_len: int):
     layers cap at their window). Ring indexing then continues writing at
     ``pos % T`` without evicting live context."""
     out = {}
-    for i, kind in enumerate(cfg.pattern):
-        key = f"slot{i}"
+    for key, kind in _cache_kinds(cfg):
         if key not in caches:
             continue
         c = caches[key]
@@ -379,7 +536,7 @@ def grow_caches(cfg, caches, new_len: int):
         t_new = new_len
         if kind == "attn_local" or (kind == "attn" and cfg.window is not None):
             t_new = min(new_len, cfg.window)
-        t_cur = c["k"].shape[2]  # stacked: (R, B, T, K, D)
+        t_cur = c["k"].shape[2]  # stacked: (R or J, B, T, K, D)
         if t_new <= t_cur:
             out[key] = c
             continue
@@ -399,8 +556,7 @@ def consolidate_caches(cfg, caches):
     Prefix writes use ring semantics (slot = pos % T) with out-of-range
     drops, so windowed and full layers share the path."""
     out = {}
-    for i, kind in enumerate(cfg.pattern):
-        key = f"slot{i}"
+    for key, kind in _cache_kinds(cfg):
         if key not in caches:
             continue
         c = caches[key]

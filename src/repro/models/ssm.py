@@ -3,7 +3,8 @@
 Layout follows Dao & Gu [arXiv:2405.21060]: separate projections for
 z (gate), x, B, C, dt (kept as distinct weights so each shards cleanly —
 see sharding/partition.py), a short causal depthwise conv over x/B/C,
-the SSD recurrence (via ``repro.kernels.ssd``), a gated RMSNorm and the
+the SSD recurrence (via ``repro.kernels.ssd``), a gated RMSNorm (per
+group of B/C, as Zamba2 normalises) and the
 output projection. Decode carries (conv tail, SSD state) per layer.
 """
 
@@ -62,6 +63,18 @@ def _causal_conv(x: jax.Array, w: jax.Array,
     return jax.nn.silu(out)
 
 
+def _gated_norm(cfg, y, z, scale):
+    """RMSNorm of ``y * silu(z)`` over each of the ``ssm_groups`` groups of
+    channels (Zamba2's per-group norm; over all channels at one group)."""
+    h = y * jax.nn.silu(z)
+    g = cfg.ssm_groups
+    if g == 1:
+        return rms_norm(h, scale, cfg.norm_eps)
+    shape = h.shape
+    h = h.reshape(shape[:-1] + (g, shape[-1] // g))
+    return rms_norm(h, scale.reshape(g, -1), cfg.norm_eps).reshape(shape)
+
+
 def _project(cfg, p, h):
     cdt = h.dtype
     z = h @ p["wz"].astype(cdt)
@@ -109,7 +122,7 @@ def ssm_forward(cfg, p: Dict[str, jax.Array], h: jax.Array,
                 chunk=cfg.ssm_chunk, d_skip=p["d_skip"].astype(jnp.float32),
             )
     y = y.reshape(bsz, l, d_in)
-    y = rms_norm(y * jax.nn.silu(z), p["norm"])
+    y = _gated_norm(cfg, y, z, p["norm"])
     out = y @ p["wo"].astype(y.dtype)
     if build_cache:
         cdt = jnp.dtype(cfg.compute_dtype) if hasattr(cfg, "compute_dtype") else x_raw.dtype
@@ -167,5 +180,5 @@ def ssm_decode(
         )
     new_cache["state"] = state
     y = y.reshape(bsz, 1, cfg.ssm_d_inner)
-    y = rms_norm(y * jax.nn.silu(z), p["norm"])
+    y = _gated_norm(cfg, y, z, p["norm"])
     return y @ p["wo"].astype(y.dtype), new_cache

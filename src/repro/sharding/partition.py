@@ -59,7 +59,8 @@ def param_pspec(path: Tuple[str, ...], leaf, mesh: Mesh, cfg) -> P:
     fsdp = fsdp_axes(mesh)
     names = [getattr(p, "key", getattr(p, "name", str(p))) for p in path]
     name = names[-1]
-    stacked = "slots" in names  # leading scan-repeat dim
+    # leading scan-repeat dim; a hybrid's shared blocks and invocations
+    stacked = bool({"slots", "shared", "hybrid"} & set(names))
     shape = leaf.shape[1:] if stacked else leaf.shape
 
     def out(*spec):

@@ -4,7 +4,9 @@
   and writes each layer's row back; it must equal the layer scan that
   takes the caches as ``xs`` and returns them as ``ys`` (the oracle
   below), in logits and in every cache leaf, and its unrolled form must
-  equal its looped one.
+  equal its looped one. A Zamba2 hybrid unrolls its layers and writes
+  each invocation's token into the stacked hot rings; its oracle decodes
+  layer by layer, each invocation's row as a cache of its own.
 * ``ssd_decode_step`` forms ``y`` from the old state; stepped over a
   sequence it must equal the token-by-token and the chunked references.
 """
@@ -25,23 +27,26 @@ from repro.kernels.ssd.ref import (
 from repro.models import lm
 from repro.models.attention import attn_decode
 from repro.models.common import rms_norm
+from repro.models.mlp import lora_mlp_forward
 from repro.models.ssm import ssm_decode
 
-ARCHS = ["mamba2-130m", "zamba2-2.7b", "gemma2-2b"]
+ARCHS = ["mamba2-130m", "zamba2-2.7b", "zamba2-7b-l24", "gemma2-2b"]
 BATCH, PROMPT, STEPS = 2, 8, 5
 
 
 def _scan_decode(cfg, params, token, pos, caches):
     """Oracle: the layer loop as a scan over (parameter rows, cache rows),
-    the new caches stacked as its outputs."""
-    shared = params.get("shared")
+    the new caches stacked as its outputs (for a hybrid, the layers one by
+    one, each invocation's cache row decoded as a cache of its own)."""
+    if cfg.hybrid_layer_ids:
+        return _hybrid_decode(cfg, params, token, pos, caches)
 
     def body(x, xs):
         slot_rows, cache_rows = xs
         new_caches = {}
         for i, kind in enumerate(cfg.pattern):
             key = f"slot{i}"
-            bp = shared if kind == "shared_attn" else slot_rows[key]
+            bp = slot_rows[key]
             if kind == "ssm":
                 y, new_caches[key] = ssm_decode(
                     cfg, bp["ssm"], rms_norm(x, bp["ln"]), cache_rows[key])
@@ -56,6 +61,41 @@ def _scan_decode(cfg, params, token, pos, caches):
     x = lm._embed(cfg, params, token)
     x, new_caches = jax.lax.scan(body, x, (params["slots"], caches))
     return lm._head(cfg, params, x)[:, 0], new_caches, pos + 1
+
+
+def _hybrid_decode(cfg, params, token, pos, caches):
+    """Zamba-2's layer equations, layer by layer, new cache rows stacked."""
+    def row(tree, i):
+        return jax.tree.map(lambda a: a[i], tree)
+
+    eps, ids = cfg.norm_eps, list(cfg.hybrid_layer_ids)
+    x = e = lm._embed(cfg, params, token)
+    states, kvs = [], []
+    for i in range(cfg.num_layers):
+        h = x
+        if i in ids:
+            j = ids.index(i)
+            sp = row(params["shared"], j % cfg.num_mem_blocks)
+            hp = row(params["hybrid"], j)
+            a, new = attn_decode(
+                cfg, sp["attn"],
+                rms_norm(jnp.concatenate([x, e], -1), sp["ln1"], eps), pos,
+                row(caches["hybrid"], j))
+            kvs.append(new)
+            f = lora_mlp_forward(sp["mlp"], hp["lora_a"], hp["lora_b"],
+                                 rms_norm(a, sp["ln2"], eps))
+            h = x + f @ hp["proj"].astype(f.dtype)
+        lp = row(params["slots"]["slot0"], i)
+        y, new = ssm_decode(cfg, lp["ssm"], rms_norm(h, lp["ln"], eps),
+                            row(caches["slot0"], i))
+        states.append(new)
+        x = x + y
+
+    def stack(rows):
+        return jax.tree.map(lambda *a: jnp.stack(a), *rows)
+
+    return (lm._head(cfg, params, x)[:, 0],
+            {"slot0": stack(states), "hybrid": stack(kvs)}, pos + 1)
 
 
 def _run(cfg, step, params, prompts, donate):

@@ -94,7 +94,7 @@ def test_consolidate_caches_roundtrip():
     )
 
 
-@pytest.mark.parametrize("arch", ["llama3.2-3b", "zamba2-2.7b"])
+@pytest.mark.parametrize("arch", ["llama3.2-3b", "zamba2-2.7b", "zamba2-7b-l24"])
 def test_serve_past_hot_ring_matches_prefill(arch):
     """Decoding more tokens than the hot ring holds keeps every generated
     token in context: the last decode step's logits equal one prefill over
