@@ -65,7 +65,9 @@ KERNEL_TOL = 2e-2
 
 #: The model path's attention and SSD implementations: attention is the
 #: chunked XLA scan of ``models/attention.py``; SSM prefill calls
-#: ``ssd_reference`` and SSM decode ``ssd_decode_step``.
+#: ``ssd_reference`` and SSM decode ``ssd_decode_layer`` (the one-pass
+#: Pallas kernel on TPU where the state is 128 wide, else
+#: ``ssd_decode_step``).
 MODEL_ATTENTION_IMPL = "xla chunked online softmax"
 MODEL_SSD_PREFILL_IMPL = "xla ssd_reference"
 
@@ -174,7 +176,7 @@ def serve_phase(cfg, requests: int, prompt_len: int, gen_len: int,
         "talp_host_parallel_eff": decode.host.parallel_efficiency,
         "attention_impl": MODEL_ATTENTION_IMPL,
         "ssd_impl": MODEL_SSD_PREFILL_IMPL + " (prefill), "
-                    "ssd_decode_step (decode)",
+                    "ssd_decode_layer (decode)",
     }
 
 

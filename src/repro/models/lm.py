@@ -216,13 +216,19 @@ def _block_decode(cfg, kind, bp, x, pos, stack, i, pre=None):
     """Decode one block against row ``i`` of its stacked cache; returns the
     new activation and the stack with that row updated in place. The row's
     write-back runs in the block's scope, so the state traffic is named by
-    the layer it belongs to. ``pre`` as in :func:`_block_fwd`."""
-    cache = _row(stack, i)
+    the layer it belongs to. An SSM block is given its SSD state as the
+    whole stack and updates row ``i`` itself; its conv tails are read and
+    written back as rows. ``pre`` as in :func:`_block_fwd`."""
     if kind == "ssm":
         with jax.named_scope("ssm"):
             h = rms_norm(x if pre is None else x + pre, bp["ln"], cfg.norm_eps)
-            y, new = ssm_decode(cfg, bp["ssm"], h, cache)
-            return x + y, _write_rows(stack, cache, new, i)
+            tails = {k: v for k, v in stack.items() if k != "state"}
+            rows = _row(tails, i)
+            y, new = ssm_decode(cfg, bp["ssm"], h,
+                                dict(rows, state=stack["state"]), layer=i)
+            state = new.pop("state")
+            return x + y, dict(_write_rows(tails, rows, new, i), state=state)
+    cache = _row(stack, i)
     with jax.named_scope("attn"):
         h = rms_norm(x, bp["ln1"], cfg.norm_eps)
         y, new = attn_decode(cfg, bp["attn"], h, pos, cache, kind)

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.ssd import ops as ssd_ops
+from ..kernels.ssd.decode import ssd_decode_layer
 from ..kernels.ssd.ref import ssd_decode_step
 from .common import rms_norm, truncated_normal
 
@@ -151,9 +152,13 @@ def init_ssm_cache(cfg, batch: int, dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
 
 
 def ssm_decode(
-    cfg, p: Dict[str, jax.Array], h: jax.Array, cache: Dict[str, jax.Array]
+    cfg, p: Dict[str, jax.Array], h: jax.Array, cache: Dict[str, jax.Array],
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode. h: (B, 1, M)."""
+    """One-token decode. h: (B, 1, M). Without ``layer`` the cache is one
+    layer's; with it, ``cache["state"]`` is the stack of every layer's SSD
+    state, updated in place at row ``layer`` (``ssd_decode_layer``), and
+    the conv tails are that layer's."""
     bsz = h.shape[0]
     nh, hp = cfg.ssm_heads, cfg.ssm_head_dim
     g, n = cfg.ssm_groups, cfg.ssm_state
@@ -168,16 +173,15 @@ def ssm_decode(
                                           axis=1)
     x, b, c = outs["conv_x"], outs["conv_b"], outs["conv_c"]
     a = -jnp.exp(p["a_log"].astype(jnp.float32))
+    args = (x[:, 0].reshape(bsz, nh, hp), dt[:, 0], a,
+            b[:, 0].reshape(bsz, g, n), c[:, 0].reshape(bsz, g, n),
+            cache["state"])
+    d_skip = p["d_skip"].astype(jnp.float32)
     with jax.named_scope("state_update"):   # the SSD recurrence
-        y, state = ssd_decode_step(
-            x[:, 0].reshape(bsz, nh, hp),
-            dt[:, 0],
-            a,
-            b[:, 0].reshape(bsz, g, n),
-            c[:, 0].reshape(bsz, g, n),
-            cache["state"],
-            d_skip=p["d_skip"].astype(jnp.float32),
-        )
+        if layer is None:
+            y, state = ssd_decode_step(*args, d_skip=d_skip)
+        else:
+            y, state = ssd_decode_layer(*args, layer, d_skip=d_skip)
     new_cache["state"] = state
     y = y.reshape(bsz, 1, cfg.ssm_d_inner)
     y = _gated_norm(cfg, y, z, p["norm"])

@@ -107,3 +107,57 @@ def test_serve_step_updates_caches_in_place(arch, batch, most, one_chip):
     # compiler's prefetch of a small stack, not a second one in HBM
     copied = re.findall(r"= (\S+?)\{[^}S]*\} copy\(", step.as_text())
     assert not stacks & set(copied)
+
+
+#: What may hold or touch the SSD state stack, or a row of it, in the
+#: compiled serve step when the one-pass kernel updates it: the step's
+#: arguments and results, the layer loop's carry, and the kernel.
+PASS_THROUGH = {"parameter", "get-tuple-element", "tuple", "while", "bitcast",
+                "custom-call"}
+INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT\s+)?%(\S+)\s*=\s*(.*?)\s([a-z][\w-]*)\((.*?)\)(?:,|$)")
+
+
+# (arch, batch, kernel calls per step): mamba2-130m's state (N 128) fills
+# the lanes and every layer runs the kernel; Zamba2's (N 64) is kept with
+# the heads minor by the chip's compiler, and its layers keep the jnp step.
+@pytest.mark.parametrize("arch,batch,calls", [
+    ("mamba2-130m", 256, 24),
+    ("zamba2-7b-l24", 16, 0),
+])
+def test_serve_step_updates_the_ssd_state_in_one_pass(arch, batch, calls,
+                                                      one_chip):
+    """The kernel runs once per layer, and where it runs nothing else reads
+    or writes the state: no reduction over a row, no second pass."""
+    cfg = get_config(arch)
+    caches = jax.eval_shape(
+        lambda: lm.init_decode_caches(cfg, batch, cache_len=2048))
+
+    def spec(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    text = jax.jit(make_serve_step(cfg), donate_argnums=3).lower(
+        spec(serve_params_shapes(cfg)),
+        jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip),
+        spec(caches)).compile().as_text()
+    sites = [line for line in text.splitlines()
+             if "custom-call(" in line and "ssd_decode_update" in line]
+    # a call in the layer loop's body runs once per layer
+    per_step = sum(cfg.repeats if "/while/body/" in line else 1
+                   for line in sites)
+    assert per_step == calls
+    if not calls:
+        return
+    state = caches["slot0"]["state"].shape
+    shapes = {"f32[%s]" % ",".join(map(str, s))
+              for s in (state, state[1:], (1,) + state[1:])}
+    instrs = [m.groups() for m in map(INSTRUCTION.match, text.splitlines())
+              if m]
+    held = {name for name, shape, _, _ in instrs
+            if shape.split("{")[0] in shapes}
+    touching = {op for name, _, op, operands in instrs
+                if name in held or held & set(re.findall(r"%([\w.-]+)",
+                                                         operands))}
+    assert touching <= PASS_THROUGH, touching - PASS_THROUGH

@@ -167,12 +167,13 @@ def test_a_perturbation_acts_from_its_invocation_on(setup, what, path, index,
 
 
 # sha256 of ``jax.jit(serve_step, donate_argnums=3).lower(...).as_text()``
-# for mamba2-130m (jax 0.9.0) before Zamba2 had a path of its own: the
-# lowered module is what XLA compiles, so an equal text is an equal step,
-# logits and caches bit for bit.
+# for mamba2-130m (jax 0.9.0, lowered for the CPU), with each layer's SSD
+# state updated in the stack by ``ssd_decode_layer``: the lowered module is
+# what XLA compiles, so an equal text is an equal step, logits and caches
+# bit for bit. Zamba2's path must leave it as it is.
 PURE_SSM_STEP = {
-    "smoke": ("6d8257ad8d1b859727d24e8ca398da1dd8f0c84c02ecb1e1e5db467771a82d5b", 2),
-    "full": ("ee09af188e16f1e1df8559df8eb9d8e1e2c9574078d342e35a60c02d5a36fbd7", 256),
+    "smoke": ("a7f10a428ae82048e9d2300e2454893bd68e06601f71747fcb0674490e19e095", 2),
+    "full": ("b3fd29ac7b3392c47476f5cb2f27d49c9d17c37afe12f122658af13a2ba9b81e", 256),
 }
 
 
