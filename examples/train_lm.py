@@ -32,7 +32,7 @@ def main():
         seq_len=128,
         ckpt_dir=ckpt,
         ckpt_every=50,
-        talp_interval=50,
+        talp_sample_every=50,
         opt_cfg=AdamWConfig(lr=1e-3, warmup_steps=20, total_steps=args.steps),
     )
     losses = [h["loss"] for h in history]
