@@ -5,10 +5,16 @@ caches, then decodes tokens autoregressively. Host/device states are
 TALP-monitored exactly as in training — the serving profile typically
 shows high Offload (host blocked on decode steps) and the per-step
 Orchestration gap, which is the paper's framing for "the host cannot
-feed the device." Each decode step's phases (``serve.fetch``,
-``serve.feed``, ``serve.dispatch``, ``serve.sample``, ``serve.flush``)
-and TALP's own scopes are profiler spans, so a ``jax.profiler`` trace
-puts every idle gap of the device down to one of them.
+feed the device."
+
+Greedy decoding needs no host between steps: one program per token
+returns the next tokens beside the logits, and they stay on the device
+as the next step's input. So the loop keeps one step in flight: it
+dispatches step t, then blocks on step t-1 and fetches its tokens while
+step t runs. Each decode step's phases (``serve.dispatch``,
+``serve.flush``, ``serve.fetch``) and TALP's own scopes are profiler
+spans, so a ``jax.profiler`` trace puts every idle gap of the device
+down to one of them.
 
 Usage (CPU-sized):
   PYTHONPATH=src python -m repro.launch.serve --arch gemma2-2b --smoke \
@@ -48,6 +54,7 @@ class ServeResult(NamedTuple):
     prompts: jax.Array      # (requests, prompt_len[, d_model]) inputs
     logits: jax.Array       # (requests, padded_vocab) last decode step
     token_s: np.ndarray     # (gen_len,) wall seconds of each decode step
+    ahead_steps: int        # steps dispatched before the previous one ended
 
 
 def serve(
@@ -81,7 +88,23 @@ def serve(
         params = jax.block_until_ready(params)
 
     prefill_fn = jax.jit(make_prefill_step(cfg))
-    decode_fn = jax.jit(make_serve_step(cfg), donate_argnums=3)
+    model_step = make_serve_step(cfg)
+
+    def greedy(logits):
+        """The next tokens: the first index of the largest logit over the
+        real vocabulary (the padded tail is left out)."""
+        return jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+
+    def serve_step(params, inp, pos, caches):
+        """One token: the decode step and its greedy sample, in one
+        program. ``inp`` is the (B,) tokens of the step before, or the
+        (B, 1, d_model) frames of an embed frontend."""
+        if cfg.frontend == "token":
+            inp = inp[:, None]
+        logits, caches, pos = model_step(params, inp, pos, caches)
+        return greedy(logits), logits, caches, pos
+
+    decode_fn = jax.jit(serve_step, donate_argnums=3)
     # Flushes the hot ring into the prefix cache before the ring wraps
     # (``attn_decode`` writes it at ``pos % decode_hot_len``).
     consolidate_fn = jax.jit(functools.partial(lm.consolidate_caches, cfg),
@@ -96,7 +119,6 @@ def serve(
             key, (requests, prompt_len, cfg.d_model), jnp.bfloat16
         )
 
-    tokens_out = []
     token_s = np.zeros(gen_len)
     with mon.region("prefill"):
         h = backend.launch(prefill_fn, params, prompts, name="prefill")
@@ -105,36 +127,44 @@ def serve(
     with mon.region("grow_cache"):
         caches = lm.grow_caches(cfg, caches, prompt_len + gen_len)
 
-    tok = jnp.argmax(logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+    tok = greedy(logits)
+    tokens_out = [np.asarray(tok)]
+    # The embed-frontend stub feeds a zero frame embedding every step.
+    frames = (None if cfg.frontend == "token" else
+              jnp.zeros((requests, 1, cfg.d_model), jnp.bfloat16))
+    prev, ahead_steps = None, 0   # prev: step t-1's handle
     with mon.region("decode"):
         for t in range(gen_len):
             t0 = time.perf_counter()
             with session.step():
-                with spans.span("serve.fetch"):
-                    tokens_out.append(np.asarray(tok))
-                with spans.span("serve.feed"):
-                    if cfg.frontend == "token":
-                        inp = tok[:, None]
-                    else:  # embed-frontend stub: feed a frame embedding
-                        inp = jnp.zeros((requests, 1, cfg.d_model),
-                                        jnp.bfloat16)
-                with spans.span("serve.dispatch"):
-                    h = backend.launch(decode_fn, params, inp, pos, caches,
-                                       name=f"decode_{t}")
-                with mon.offload():
-                    logits, caches, pos = backend.wait(h)
-                with spans.span("serve.sample"):
-                    tok = jnp.argmax(
-                        logits[:, : cfg.vocab_size], -1).astype(jnp.int32)
+                # Step t-1 still running: the device has work queued.
+                ahead = int(prev is not None and not tok.is_ready())
+                ahead_steps += ahead
+                with spans.span("serve.dispatch", ahead=ahead):
+                    h = backend.launch(
+                        decode_fn, params, tok if frames is None else frames,
+                        pos, caches, name=f"decode_{t}")
+                tok, logits, caches, pos = h.out
+                # The handle waits on what no later program consumes: the
+                # caches are donated to the next step or to the flush.
+                h.out = tok, logits
                 if (t + 1) % cfg.decode_hot_len == 0 and t + 1 < gen_len:
                     with spans.span("serve.flush"):
                         caches = consolidate_fn(caches)
+                if prev is not None:
+                    with mon.offload():
+                        prev_tok, _ = backend.wait(prev)
+                    with spans.span("serve.fetch"):
+                        tokens_out.append(np.asarray(prev_tok))
+                prev = h
             token_s[t] = time.perf_counter() - t0
             session.tick(t)
+        with mon.offload():
+            _, logits = backend.wait(prev)
 
     result = session.finish()
     return ServeResult(np.stack(tokens_out, axis=1), result, params,
-                       prompts, logits, token_s)
+                       prompts, logits, token_s, ahead_steps)
 
 
 def parser() -> argparse.ArgumentParser:

@@ -253,12 +253,15 @@ def serve_trace(tmp_path_factory):
 def test_traced_serve_holds_the_program_spans(serve_trace):
     from jax.profiler import ProfileData
 
-    names = {e.name for p in ProfileData.from_file(serve_trace).planes
-             for line in p.lines for e in line.events}
+    events = [e for p in ProfileData.from_file(serve_trace).planes
+              for line in p.lines for e in line.events]
+    names = {e.name for e in events}
     for want in ("talp.region.decode_step", "talp.offload",
-                 "talp.capture.step", "serve.fetch", "serve.dispatch",
-                 "serve.feed", "serve.sample"):
+                 "talp.capture.step", "serve.fetch", "serve.dispatch"):
         assert want in names, want
+    ahead = [dict(e.stats).get("ahead") for e in events
+             if e.name == "serve.dispatch"]
+    assert len(ahead) == 4 and set(ahead) <= {0, 1}, ahead
     steps = sorted(dict(e.stats)["step"]
                    for p in ProfileData.from_file(serve_trace).planes
                    for line in p.lines for e in line.events
